@@ -74,7 +74,10 @@ def main() -> None:
                             robust_bench, roofline, steering_bench,
                             tab1_workloads)
     from benchmarks.common import OUT_DIR, save_json
+    from repro.compile_cache import enable_compile_cache
     from repro.obs import TRACER
+
+    enable_compile_cache()
 
     if args.trace:
         TRACER.enable()

@@ -147,11 +147,8 @@ class _Fitness:
             and self.problem.n <= opts.jax_task_limit)
         self._jd = None
         if use_jax:
-            try:
-                from repro.core.des_jax import JaxDES
-                self._jd = JaxDES(self.problem)
-            except Exception:   # pragma: no cover - jax always available here
-                self._jd = None
+            from repro.core.des_jax import JaxDES
+            self._jd = JaxDES(self.problem)
 
     def __call__(self, genomes: list[np.ndarray]) -> np.ndarray:
         out = np.empty(len(genomes))

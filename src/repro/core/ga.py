@@ -336,11 +336,8 @@ class BatchedFitness(_CachedFitness):
         super().__init__(space, opts, self.problem.n)
         self._jd = None
         if self._use_jax and space.E > 0:
-            try:
-                from repro.core.des_jax import JaxDES
-                self._jd = JaxDES(self.problem, options=opts.des_options)
-            except Exception:   # pragma: no cover - jax always available here
-                self._jd = None
+            from repro.core.des_jax import JaxDES
+            self._jd = JaxDES(self.problem, options=opts.des_options)
 
     def _raw_makespans(self, genomes: np.ndarray) -> np.ndarray:
         """Makespan (INF if infeasible) for each unique genome row."""
@@ -503,12 +500,9 @@ class EnsembleFitness(_CachedFitness):
         self.weights = np.asarray(ensemble.weights, dtype=np.float64)
         self._jd = None
         if self._use_jax and space.E > 0:
-            try:
-                from repro.core.des_jax import EnsembleJaxDES
-                self._jd = EnsembleJaxDES(self.problems,
-                                          options=opts.des_options)
-            except Exception:   # pragma: no cover - jax always available here
-                self._jd = None
+            from repro.core.des_jax import EnsembleJaxDES
+            self._jd = EnsembleJaxDES(self.problems,
+                                      options=opts.des_options)
 
     def scalarize(self, ms: np.ndarray) -> np.ndarray:
         """(S, M) member makespans -> (S,) objective values (INF-safe)."""
@@ -1132,11 +1126,8 @@ def trim_ports_ensemble(ensemble: DagEnsemble, x: np.ndarray,
             or (backend == "auto"
                 and max(p.n for p in problems) <= GAOptions.jax_task_limit
                 and E >= 16 and droppable_total >= 32)):
-        try:
-            from repro.core.des_jax import EnsembleJaxDES
-            jd = EnsembleJaxDES(problems)
-        except Exception:   # pragma: no cover - jax always available here
-            jd = None
+        from repro.core.des_jax import EnsembleJaxDES
+        jd = EnsembleJaxDES(problems)
 
     ptr = 0   # cyclic sweep pointer (matches trim_ports' pair ordering)
     while True:
@@ -1228,11 +1219,8 @@ def trim_ports(dag: CommDAG, x: np.ndarray, rel_tol: float = 1e-6,
     if backend == "jax" or (backend == "auto"
                             and problem.n <= GAOptions.jax_task_limit
                             and E >= 16 and droppable_total >= 32):
-        try:
-            from repro.core.des_jax import JaxDES
-            jd = JaxDES(problem)
-        except Exception:   # pragma: no cover - jax always available here
-            jd = None
+        from repro.core.des_jax import JaxDES
+        jd = JaxDES(problem)
 
     ptr = 0   # cyclic sweep pointer (matches the legacy pair ordering)
     while True:

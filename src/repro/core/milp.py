@@ -762,14 +762,15 @@ def solve_resilient(dag: CommDAG, opts: MILPOptions | None = None, *,
                     current_x: np.ndarray | None = None,
                     mask: np.ndarray | None = None) -> MILPResult:
     """MILP solve with a wall-clock budget, retry/backoff on solver
-    exceptions, and a graceful fallback chain that ALWAYS returns a valid
-    plan:
+    exceptions, and a graceful fallback chain that returns a valid plan
+    whenever the solvers are only infeasible or out of time:
 
       1. `solve_delta_milp` under the remaining budget (retried with
          backoff on exceptions; a budget expiry without an incumbent reads
          infeasible via the finite-makespan guard and falls through),
-      2. a GA incumbent (`delta_fast`) converted to a schedule by
-         `result_from_topology`,
+      2. a feasible GA incumbent (`delta_fast`) converted to a schedule
+         by `result_from_topology` (an error raised by the GA or its DES
+         propagates: it is a fault, not an infeasible solve),
       3. the current plan `current_x` with failed links masked (one
          circuit everywhere if no current plan exists).
 
@@ -805,20 +806,17 @@ def solve_resilient(dag: CommDAG, opts: MILPOptions | None = None, *,
     _FALLBACKS.inc(stage="milp")
 
     # ---- stage 2: GA incumbent
-    try:
-        from repro.core.ga import delta_fast
-        ga = delta_fast(dag, ga_options)
-        if ga.feasible:
-            res = result_from_topology(dag, ga.x, status="feasible")
-            if res.feasible:
-                res.degraded = True
-                res.fallback_stage = "ga"
-                res.stats["resilient"] = {"milp_error": last_error,
-                                          "budget_s": budget}
-                _FALLBACKS.inc(stage="ga")
-                return res
-    except Exception as exc:   # pragma: no cover - GA is pure numpy/jax
-        last_error = f"{last_error}; ga {type(exc).__name__}: {exc}"
+    from repro.core.ga import delta_fast
+    ga = delta_fast(dag, ga_options)
+    if ga.feasible:
+        res = result_from_topology(dag, ga.x, status="feasible")
+        if res.feasible:
+            res.degraded = True
+            res.fallback_stage = "ga"
+            res.stats["resilient"] = {"milp_error": last_error,
+                                      "budget_s": budget}
+            _FALLBACKS.inc(stage="ga")
+            return res
 
     # ---- stage 3: the current plan, failed links masked
     if current_x is None:

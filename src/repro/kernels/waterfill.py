@@ -16,9 +16,9 @@ is streamed through VMEM exactly once for both reductions.
 two per-task vectors of one filling round (active flow levels, unfrozen
 mask) and returns the per-constraint `(used, denom)` pair.  The DES event
 loop (`repro.core.des_jax._maxmin`) calls it once per filling round; it is
-vmap-safe (batched over GA populations and ensemble members) and runs in
-interpret mode off-TPU, where `repro.kernels.ref.fill_round_ref` is the
-production fallback.
+vmap-safe (batched over GA populations and ensemble members).  On the CPU
+backend the DES uses `repro.kernels.ref.fill_round_ref` instead; interpret
+mode is for tests.
 """
 from __future__ import annotations
 

@@ -14,6 +14,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ALL_ARCHS, make_job
 from repro.core.api import METHODS, PlanRequest, plan
 from repro.core.ga import GAOptions
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--time-limit", type=float, default=300.0)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = ALL_ARCHS[args.arch]
     job = make_job(arch, seq_len=args.seq,

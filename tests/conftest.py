@@ -8,13 +8,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import strategies as st
-except ModuleNotFoundError:   # container image without hypothesis
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
-    from hypothesis import strategies as st
+from hypothesis import strategies as st
 
 from repro.core.cluster import ClusterSpec
 from repro.core.dag import CommDAG, CommTask, Dep, make_virtual

@@ -45,7 +45,7 @@ def test_property_budget_symmetry_connectivity(dag):
     DAGs: per-pod port budgets are never exceeded, the allocation is a
     symmetric matrix with an empty diagonal, and every active pair gets at
     least one circuit (connectivity before any weighting rule spends the
-    remaining budget).  Runs under tests/_hypothesis_stub.py too."""
+    remaining budget)."""
     U = dag.cluster.port_limits
     pairs = dag.undirected_pairs()
     for name, fn in BASELINES.items():

@@ -52,12 +52,8 @@ _SUBPROC = textwrap.dedent("""
         exact = jax.lax.psum(v, "data")
         return total[None], res[None], exact[None]
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:               # jax < 0.5: experimental namespace
-        from jax.experimental.shard_map import shard_map
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                           out_specs=P("data")))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                               out_specs=P("data")))
     total, res, exact = fn(jnp.asarray(x))
     total, res, exact = map(np.asarray, (total, res, exact))
     scale = np.abs(x).max() * 4 / 127

@@ -8,15 +8,8 @@ import json
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:   # container image without hypothesis
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from conftest import gpt7b_job, one_circuit_topology
 from repro.core.cluster import ClusterSpec, split_port_budgets
@@ -242,7 +235,7 @@ def test_transition_reprices_against_midstream_plane_failure(tiny_dag):
     assert all(np.isfinite(s.peak_inflation) for s in tr.steps)
 
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(st.integers(0, 2**31 - 1))
 def test_random_transitions_one_plane_dark_invariant(seed):
     """Property (ISSUE S3): for random A->B plan pairs, every intermediate
@@ -312,7 +305,7 @@ def test_plane_failure_draws_are_collision_free():
 
 
 # --------------------------------------------- health round-trip (S2)
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 2**31 - 1))
 def test_health_snapshot_roundtrip_under_plane_churn(seed):
     rng = np.random.default_rng(seed)
