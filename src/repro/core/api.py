@@ -41,6 +41,7 @@ from repro.core.ga import (GAOptions, GAResult, delta_failsafe, delta_fast,
                            delta_robust, ROBUST_OBJECTIVES)
 from repro.core.milp import (MILPOptions, MILPResult, solve_delta_milp,
                              solve_resilient, solve_robust_milp)
+from repro.obs.tracing import span
 
 # DES engine knobs + jit-churn accounting, re-exported so callers tuning
 # the evaluation engine (kernel backend, compile buckets) need only the
@@ -506,26 +507,41 @@ class PlanRequest:
         return given[0]
 
 
+# the method each kind runs where the request names none (failsafe,
+# resilient and fleet requests have one path each and ignore `method`)
+_KIND_METHOD = {"dag": "delta-fast", "ensemble": "delta-robust",
+                "failsafe": "delta-failsafe", "resilient": "delta-resilient",
+                "fleet": "fleet"}
+
+
 def plan(request: PlanRequest):
     """THE planner entry point: dispatch a `PlanRequest` by `kind`.
 
     Returns `PlanResult` (dag / failsafe / resilient),
     `EnsemblePlanResult` (ensemble) or `FleetPlanResult` (fleet) -- the
     same objects, bit-identical, that the legacy facades produced.
+    Traced as the `plan` span, the root of the request's spans.
     """
     kind = request.kind
+    method = _KIND_METHOD[kind]
+    if kind in ("dag", "ensemble"):
+        method = request.method or method
+    with span("plan", kind=kind, method=method):
+        return _dispatch(request, kind, method)
+
+
+def _dispatch(request: PlanRequest, kind: str, method: str):
     ga = request.ga_options
     if request.des_options is not None:
         ga = dataclasses.replace(ga or GAOptions(),
                                  des_options=request.des_options)
     if kind == "dag":
-        return _plan_dag(request.dag, method=request.method or "delta-fast",
+        return _plan_dag(request.dag, method=method,
                          port_min=request.port_min, ga_options=ga,
                          milp_options=request.milp_options,
                          ideal_result=request.ideal_result)
     if kind == "ensemble":
-        return _plan_ensemble(request.ensemble,
-                              method=request.method or "delta-robust",
+        return _plan_ensemble(request.ensemble, method=method,
                               objective=request.objective or "max-regret",
                               refs=request.refs, ga_options=ga,
                               milp_options=request.milp_options)

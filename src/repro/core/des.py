@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.dag import VIRTUAL, CommDAG
+from repro.obs.tracing import span
 
 INF = float("inf")
 
@@ -110,15 +111,23 @@ def maxmin_fair_rates(problem: DESProblem, active: np.ndarray,
     until a constraint saturates; freeze its tasks; repeat.
     Returns task rates r_m = F_m * phi_m (0 for inactive tasks).
     """
+    return _filled_rates(problem, active, caps)[0]
+
+
+def _filled_rates(problem: DESProblem, active: np.ndarray,
+                  caps: np.ndarray) -> tuple[np.ndarray, int]:
+    """`maxmin_fair_rates` and the number of filling rounds it took."""
     n = problem.n
     phi = np.zeros(n)
     unfrozen = active.copy()
     ct, cw, cp = problem.con_task, problem.con_w, problem.con_ptr
     act_w = np.where(active[ct], cw, 0.0)
+    rounds = 0
 
     for _ in range(problem.num_cons + 1):
         if not unfrozen.any():
             break
+        rounds += 1
         unf_w = np.where(unfrozen[ct], cw, 0.0)
         used = np.add.reduceat(act_w * phi[ct], cp[:-1]) \
             if len(ct) else np.zeros(0)
@@ -141,7 +150,7 @@ def maxmin_fair_rates(problem: DESProblem, active: np.ndarray,
             break
         for ci in np.nonzero(sat)[0]:
             unfrozen[ct[cp[ci]:cp[ci + 1]]] = False
-    return problem.flows * phi * active
+    return problem.flows * phi * active, rounds
 
 
 # -------------------------------------------------------------------- result
@@ -172,7 +181,22 @@ class DESResult:
 def simulate(problem: DESProblem, x: np.ndarray, ideal: bool = False,
              record_rates: bool = False, max_events: int | None = None
              ) -> DESResult:
-    """Run the DES for topology matrix x (symmetric, circuits per pair)."""
+    """Run the DES for topology matrix x (symmetric, circuits per pair).
+
+    Traced as the `des.exact` span, with the event loop's trips (`events`)
+    and the filling rounds of every rate computation (`rounds`)."""
+    with span("des.exact", n=problem.n, ideal=bool(ideal)) as sp:
+        res, trips, rounds = _simulate(problem, x, ideal, record_rates,
+                                       max_events)
+        sp.set(events=trips, rounds=rounds)
+    return res
+
+
+def _simulate(problem: DESProblem, x: np.ndarray, ideal: bool,
+              record_rates: bool, max_events: int | None
+              ) -> tuple[DESResult, int, int]:
+    """`simulate`, with the event loop's trips and the filling rounds
+    summed over its rate computations."""
     n = problem.n
     caps = problem.link_caps(np.asarray(x), ideal=ideal)
     rem = problem.volume.copy()
@@ -212,8 +236,10 @@ def simulate(problem: DESProblem, x: np.ndarray, ideal: bool = False,
     trace: list[tuple[float, float, np.ndarray]] = []
     limit = max_events or (4 * n + 8)
     feasible = True
+    trips = rounds = 0
 
     for _ in range(limit):
+        trips += 1
         # start every task whose ready time has arrived
         newly = (~started) & (missing == 0) & (ready_at <= t + 1e-15)
         if newly.any():
@@ -228,7 +254,8 @@ def simulate(problem: DESProblem, x: np.ndarray, ideal: bool = False,
             break
         active = started & ~done
         if active.any():
-            rates = maxmin_fair_rates(problem, active, caps)
+            rates, r = _filled_rates(problem, active, caps)
+            rounds += r
             act_idx = np.nonzero(active)[0]
             if (rates[act_idx] <= 0).any():
                 feasible = False  # disconnected pair under this topology
@@ -270,7 +297,7 @@ def simulate(problem: DESProblem, x: np.ndarray, ideal: bool = False,
     return DESResult(start=start, finish=finish, makespan=makespan,
                      feasible=feasible, events=ev,
                      task_interval=task_interval, critical_path=crit,
-                     crit_delta=crit_delta, rate_trace=trace)
+                     crit_delta=crit_delta, rate_trace=trace), trips, rounds
 
 
 def _intervals_of(events: np.ndarray, start: np.ndarray, finish: np.ndarray,

@@ -325,8 +325,10 @@ class _StaticCfg(NamedTuple):
 def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
               mask: jax.Array, max_events: int, backend: str = "segment",
               interpret: bool = False) -> tuple[jax.Array, jax.Array,
-                                                jax.Array, jax.Array]:
-    """Returns (makespan, feasible, start, finish).
+                                                jax.Array, jax.Array,
+                                                jax.Array]:
+    """Returns (makespan, feasible, start, finish, trips), `trips` being
+    the event loop's trip count.
 
     Event-retirement loop: every trip computes the active fair-share rates
     once, advances to the next distinct event time, and retires *all*
@@ -428,11 +430,11 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     state = (0, jnp.array(0.0), t_ready, rem, started, done, start, finish,
              missing, feasible)
     state = jax.lax.while_loop(cond, body, state)
-    _, _, _, _, _, done, start, finish, _, feasible = state
+    trips, _, _, _, _, done, start, finish, _, feasible = state
     feasible = feasible & done.all()
     makespan = jnp.where(feasible, jnp.max(jnp.where(jnp.isfinite(finish),
                                                      finish, -INF)), INF)
-    return makespan, feasible, start, finish
+    return makespan, feasible, start, finish, trips
 
 
 # ------------------------------------------------- compiled-executable LRU
@@ -502,10 +504,14 @@ class CompiledDES:
 
     @functools.cached_property
     def batch_genomes(self):
+        # (makespan, feasible, trips) per genome: each lane's own trip
+        # count, though the vmapped loop runs as long as its slowest lane
         def f(leaves, genomes, eu, ev, mask):
             def one(g):
-                return self._run(leaves, self._scatter(g, eu, ev),
-                                 jnp.asarray(False), mask)[:2]
+                ms, feas, _, _, trips = self._run(
+                    leaves, self._scatter(g, eu, ev), jnp.asarray(False),
+                    mask)
+                return ms, feas, trips
             return jax.vmap(one)(genomes)
         return self._traced("batch_genomes", jax.jit(f))
 
@@ -515,7 +521,9 @@ class CompiledDES:
         # passes jnp.ones, the k-failure objective one failure scenario
         # per stacked member -- same compiled executable either way
         def one_member(leaves, x, mask):
-            return self._run(leaves, x, jnp.asarray(False), mask)[:2]
+            ms, feas, _, _, trips = self._run(leaves, x, jnp.asarray(False),
+                                              mask)
+            return ms, feas, trips
 
         def one_genome(leaves, g, eu, ev, masks):
             x = self._scatter(g, eu, ev)
@@ -554,13 +562,14 @@ def des_cache_clear() -> None:
 
 
 def _compiled_for(cfg: _StaticCfg, pad: PadSpec,
-                  warn_on_miss: bool = False) -> CompiledDES:
+                  warn_on_miss: bool = False) -> tuple[CompiledDES, bool]:
+    """The bucket's jitted entry points, and whether the bucket existed."""
     key = (cfg, pad.d, pad.e)
     ent = _COMPILE_CACHE.get(key)
     if ent is not None:
         _HITS.inc()
         _COMPILE_CACHE.move_to_end(key)
-        return ent
+        return ent, True
     # jit churn: every miss increments des_compile_miss_total whether or
     # not the caller opted into the warning, so the counter is the one
     # authoritative recompile signal (the log line is just its echo)
@@ -578,7 +587,20 @@ def _compiled_for(cfg: _StaticCfg, pad: PadSpec,
         _COMPILE_CACHE.popitem(last=False)
         _EVICTIONS.inc()
     _ENTRIES.set(len(_COMPILE_CACHE))
-    return ent
+    return ent, False
+
+
+def _host_results(sp, ms, feas, trips) -> tuple[np.ndarray, np.ndarray]:
+    """(makespans, feasible) on the host.  With the `des.simulate` span
+    `sp` live, the lanes' trip counts come back in the same transfer and
+    go on the span: `trips` (the slowest lane's, which every lane of the
+    vmapped loop runs) and `lane_trips` (their sum); otherwise they stay
+    on the device."""
+    if not sp.live:
+        return np.asarray(ms), np.asarray(feas)
+    ms, feas, trips = jax.device_get((ms, feas, trips))
+    sp.set(trips=int(trips.max()), lane_trips=int(trips.sum()))
+    return np.asarray(ms), np.asarray(feas)
 
 
 # ------------------------------------------------------------------ engines
@@ -589,20 +611,24 @@ class JaxDES:
                  options: DESOptions | None = None):
         self.problem = problem
         self.options = options or DESOptions()
-        ropt = self.options.resolve()
-        pad = PadSpec.exact(problem)
-        if ropt.bucket:
-            pad = pad.bucketed(ropt)
-        self.pad = pad
-        self.arrays = DESArrays.from_problem(problem, pad)
-        self.max_events = int(max_events or default_max_events(pad.n))
-        cfg = _StaticCfg(n=pad.n, num_cons=pad.cons,
-                         num_link_cons=pad.links,
-                         P=problem.dag.cluster.num_pods,
-                         max_events=self.max_events, backend=ropt.backend,
-                         interpret=ropt.interpret, members=0)
-        self._compiled = _compiled_for(cfg, pad, ropt.warn_on_miss)
-        self._leaves = tuple(getattr(self.arrays, f) for f in _ARRAY_FIELDS)
+        with span("des.prepare") as sp:
+            ropt = self.options.resolve()
+            pad = PadSpec.exact(problem)
+            if ropt.bucket:
+                pad = pad.bucketed(ropt)
+            self.pad = pad
+            self.arrays = DESArrays.from_problem(problem, pad)
+            self.max_events = int(max_events or default_max_events(pad.n))
+            cfg = _StaticCfg(n=pad.n, num_cons=pad.cons,
+                             num_link_cons=pad.links,
+                             P=problem.dag.cluster.num_pods,
+                             max_events=self.max_events,
+                             backend=ropt.backend,
+                             interpret=ropt.interpret, members=0)
+            self._compiled, hit = _compiled_for(cfg, pad, ropt.warn_on_miss)
+            self._leaves = tuple(getattr(self.arrays, f)
+                                 for f in _ARRAY_FIELDS)
+            sp.set(n=pad.n, hit=hit)
         self.P = problem.dag.cluster.num_pods
 
     def _mask(self, mask) -> jax.Array:
@@ -615,14 +641,14 @@ class JaxDES:
 
     def makespan(self, x, ideal: bool = False, mask=None) -> float:
         with span("des.simulate", entry="single", n=self.pad.n):
-            ms, _, _, _ = self._compiled.single(
+            ms = self._compiled.single(
                 self._leaves, jnp.asarray(x), jnp.asarray(ideal),
-                self._mask(mask))
+                self._mask(mask))[0]
             return float(ms)
 
     def simulate(self, x, ideal: bool = False, mask=None):
         with span("des.simulate", entry="single", n=self.pad.n):
-            ms, feas, start, finish = self._compiled.single(
+            ms, feas, start, finish, _ = self._compiled.single(
                 self._leaves, jnp.asarray(x), jnp.asarray(ideal),
                 self._mask(mask))
             n = self.problem.n    # strip bucket-padding ghost tasks
@@ -646,12 +672,12 @@ class JaxDES:
         device->host for (makespan, feasible), independent of pop size."""
         genomes = jnp.asarray(genomes)
         with span("des.simulate", entry="batch_genomes", n=self.pad.n,
-                  pop=int(genomes.shape[0])):
-            ms, feas = self._compiled.batch_genomes(
+                  pop=int(genomes.shape[0])) as sp:
+            ms, feas, trips = self._compiled.batch_genomes(
                 self._leaves, genomes,
                 jnp.asarray(edge_u, dtype=jnp.int32),
                 jnp.asarray(edge_v, dtype=jnp.int32), self._mask(mask))
-            return np.asarray(ms), np.asarray(feas)
+            return _host_results(sp, ms, feas, trips)
 
 
 # ------------------------------------------------------------------ ensemble
@@ -730,20 +756,25 @@ class EnsembleJaxDES:
                  options: DESOptions | None = None):
         self.problems = problems
         self.options = options or DESOptions()
-        ropt = self.options.resolve()
-        pad = member_pad(problems)
-        if ropt.bucket:
-            pad = pad.bucketed(ropt)
-        self.pad = pad
-        self.arrays = stack_problems(problems, pad)
-        self.max_events = int(max_events or default_max_events(pad.n))
         self.P = problems[0].dag.cluster.num_pods
-        cfg = _StaticCfg(n=pad.n, num_cons=pad.cons,
-                         num_link_cons=pad.links, P=self.P,
-                         max_events=self.max_events, backend=ropt.backend,
-                         interpret=ropt.interpret, members=len(problems))
-        self._compiled = _compiled_for(cfg, pad, ropt.warn_on_miss)
-        self._leaves = tuple(getattr(self.arrays, f) for f in _ARRAY_FIELDS)
+        with span("des.prepare") as sp:
+            ropt = self.options.resolve()
+            pad = member_pad(problems)
+            if ropt.bucket:
+                pad = pad.bucketed(ropt)
+            self.pad = pad
+            self.arrays = stack_problems(problems, pad)
+            self.max_events = int(max_events or default_max_events(pad.n))
+            cfg = _StaticCfg(n=pad.n, num_cons=pad.cons,
+                             num_link_cons=pad.links, P=self.P,
+                             max_events=self.max_events,
+                             backend=ropt.backend,
+                             interpret=ropt.interpret,
+                             members=len(problems))
+            self._compiled, hit = _compiled_for(cfg, pad, ropt.warn_on_miss)
+            self._leaves = tuple(getattr(self.arrays, f)
+                                 for f in _ARRAY_FIELDS)
+            sp.set(n=pad.n, hit=hit)
 
     def _masks(self, masks) -> jax.Array:
         """(M, P, P) per-member availability factors (ones when healthy).
@@ -765,12 +796,12 @@ class EnsembleJaxDES:
         vmap'd `_simulate`)."""
         genomes = jnp.asarray(genomes)
         with span("des.simulate", entry="ensemble_genomes", n=self.pad.n,
-                  pop=int(genomes.shape[0]), members=len(self.problems)):
-            ms, feas = self._compiled.ensemble_genomes(
+                  pop=int(genomes.shape[0]), members=len(self.problems)) as sp:
+            ms, feas, trips = self._compiled.ensemble_genomes(
                 self._leaves, genomes,
                 jnp.asarray(edge_u, dtype=jnp.int32),
                 jnp.asarray(edge_v, dtype=jnp.int32), self._masks(masks))
-            return np.asarray(ms), np.asarray(feas)
+            return _host_results(sp, ms, feas, trips)
 
     def makespans(self, x, masks=None) -> tuple[np.ndarray, np.ndarray]:
         """Per-member (makespan, feasible) for one symmetric (P, P)

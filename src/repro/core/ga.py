@@ -452,16 +452,20 @@ def delta_fast(dag: CommDAG, opts: GAOptions | None = None,
     # batched jax fitness may run in float32; ~1e-5 ranking noise)
     ranked = sorted(fit.cache.items(), key=lambda kv: kv[1])[:8]
     best_x, best_ms = space.to_matrix(best_g), INF
-    for key, fval in ranked:
-        if not np.isfinite(fval):
-            continue
-        g = np.frombuffer(key, dtype=np.int64)
-        x = space.to_matrix(g)
-        ms = simulate(fit.problem, x).makespan
-        port_pen = opts.port_weight * float(g.sum())
-        if ms + port_pen < best_ms:
-            best_ms, best_x = ms + port_pen, x
-    ms = simulate(fit.problem, best_x).makespan
+    with span("ga.rerank") as sp:
+        candidates = 0
+        for key, fval in ranked:
+            if not np.isfinite(fval):
+                continue
+            candidates += 1
+            g = np.frombuffer(key, dtype=np.int64)
+            x = space.to_matrix(g)
+            ms = simulate(fit.problem, x).makespan
+            port_pen = opts.port_weight * float(g.sum())
+            if ms + port_pen < best_ms:
+                best_ms, best_x = ms + port_pen, x
+        ms = simulate(fit.problem, best_x).makespan
+        sp.set(candidates=candidates)
     return GAResult(x=best_x, makespan=float(ms), generations=gen,
                     evaluations=fit.evaluations, elapsed=time.time() - t0,
                     history=history, feasible=np.isfinite(ms))

@@ -43,6 +43,7 @@ from dataclasses import dataclass, field
 from repro.core.cluster import ClusterSpec, Placement
 from repro.core.dag import VIRTUAL, CommDAG, CommTask, Dep, make_virtual
 from repro.core.traffic import JobSpec
+from repro.obs.tracing import span
 
 
 # --------------------------------------------------------------------- 1F1B
@@ -339,15 +340,23 @@ def build_comm_dag(job: JobSpec, inter_pod_gbps: float = 400.0,
                    reverse_stages: bool = False,
                    cluster: ClusterSpec | None = None,
                    prune_dominated: bool = True) -> CommDAG:
-    """JobSpec -> reduced inter-pod CommDAG (the paper's (M, D) input)."""
-    placement = job.placement(reverse_stages)
-    if cluster is None:
-        cluster = job.cluster(inter_pod_gbps, reverse_stages=reverse_stages)
-    full = build_full_dag(job, cluster, placement,
-                          reduce_replicas=reduce_replicas)
-    meta = {"job": job.name, "full_dag": full.stats(),
-            "reduce_replicas": reduce_replicas,
-            "reverse_stages": reverse_stages,
-            "inter_pod_gbps": inter_pod_gbps}
-    return reduce_dag(full, cluster, prune_dominated=prune_dominated,
-                      meta=meta)
+    """JobSpec -> reduced inter-pod CommDAG (the paper's (M, D) input).
+
+    Traced as the `dag.build` span, with the reduced DAG's task,
+    dependency and pod counts."""
+    with span("dag.build") as sp:
+        placement = job.placement(reverse_stages)
+        if cluster is None:
+            cluster = job.cluster(inter_pod_gbps,
+                                  reverse_stages=reverse_stages)
+        full = build_full_dag(job, cluster, placement,
+                              reduce_replicas=reduce_replicas)
+        meta = {"job": job.name, "full_dag": full.stats(),
+                "reduce_replicas": reduce_replicas,
+                "reverse_stages": reverse_stages,
+                "inter_pod_gbps": inter_pod_gbps}
+        dag = reduce_dag(full, cluster, prune_dominated=prune_dominated,
+                         meta=meta)
+        sp.set(tasks=dag.num_real_tasks, deps=len(dag.deps),
+               pods=cluster.num_pods)
+    return dag

@@ -3,7 +3,7 @@
 The observability substrate for the online control plane (ROADMAP:
 "planner as a service") and for every perf PR's measurement needs:
 
-  metrics   counters/gauges/histograms with labels, JSON snapshot +
+  metrics   counters and gauges with labels, JSON snapshot +
             Prometheus text exposition, planner-scoped deltas
   tracing   nestable spans over the hot seams (GA generations, DES
             compile/simulate, MILP phases, fleet decisions), Chrome-trace
@@ -23,17 +23,16 @@ Quick start::
 """
 from repro.obs.journal import FleetJournal, rebuild_event, serialize_event
 from repro.obs.logs import get_logger, setup_logging
-from repro.obs.metrics import (REGISTRY, Counter, Gauge, Histogram,
-                               MetricsRegistry, RegistryScope, get_counter,
-                               get_gauge, get_histogram)
+from repro.obs.metrics import (REGISTRY, Counter, Gauge, MetricsRegistry,
+                               RegistryScope, get_counter, get_gauge)
 from repro.obs.timeline import (plane_rewire_timeline, schedule_timeline,
                                 slack_report, task_slack, validate_trace,
                                 write_trace)
 from repro.obs.tracing import TRACER, SpanRecord, Tracer, enabled, span
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "RegistryScope",
-    "REGISTRY", "get_counter", "get_gauge", "get_histogram",
+    "Counter", "Gauge", "MetricsRegistry", "RegistryScope",
+    "REGISTRY", "get_counter", "get_gauge",
     "Tracer", "TRACER", "SpanRecord", "span", "enabled",
     "plane_rewire_timeline", "schedule_timeline", "slack_report",
     "task_slack", "validate_trace", "write_trace",

@@ -22,11 +22,15 @@ and the CI smoke (the emitted file must stay loadable by Perfetto).
 from __future__ import annotations
 
 import json
+from typing import TYPE_CHECKING
 
 import numpy as np
 
 from repro.core.dag import VIRTUAL, CommDAG
-from repro.core.des import DESProblem, DESResult, simulate
+
+if TYPE_CHECKING:
+    # `repro.core.des` traces through this package: imported where used
+    from repro.core.des import DESProblem, DESResult
 
 __all__ = ["interval_rate_matrices", "plane_rewire_timeline",
            "schedule_timeline", "slack_report", "task_slack",
@@ -155,6 +159,7 @@ def schedule_timeline(dag: CommDAG, x: np.ndarray,
     a counter track with its per-interval utilization.  ``time_scale``
     maps seconds to trace µs (default 1:1 -- trace µs == schedule µs).
     """
+    from repro.core.des import DESProblem, simulate
     problem = DESProblem(dag)
     if result is None:
         result = simulate(problem, np.asarray(x), record_rates=True)

@@ -7,6 +7,13 @@ recorded and the stack popped either way, with the exception type attached
 to the span), and use monotonic clocks, so a span summary is a faithful
 "where did the wall clock go" decomposition.
 
+Every record carries `root`, a per-process sequence number of the
+outermost span open on its thread when it was recorded, so all spans of one
+request (one `plan()` call, one fleet event) share one identifier.  While
+tracing is enabled, each span also enters `jax.profiler.TraceAnnotation`
+under its own name: when a profiler trace runs, the spans appear in the
+trace's host plane, on the device trace's clock, beside the device ops.
+
 Cost model: tracing is DISABLED by default.  A disabled `span()` returns a
 shared no-op context manager -- one attribute check, no allocation -- so
 instrumenting per-generation / per-batch paths costs well under the 2%
@@ -19,7 +26,7 @@ Exports:
     jit-vs-simulate-vs-solve split the benchmark rows attach);
   * `Tracer.to_chrome_trace()` -- Chrome trace-event JSON (Perfetto-ready),
     one track per originating thread, nesting preserved via B/E pairs
-    rendered as complete ``X`` events.
+    rendered as complete ``X`` events, each with its `root` in its args.
 
 One process-wide default tracer (`TRACER`) is shared by all instrumented
 modules; `span(name, **attrs)` is the module-level shorthand bound to it.
@@ -27,6 +34,7 @@ modules; `span(name, **attrs)` is the module-level shorthand bound to it.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
 import threading
 import time
@@ -36,13 +44,17 @@ __all__ = ["SpanRecord", "Tracer", "TRACER", "span", "enabled"]
 
 class SpanRecord:
     """One closed span: name, [t0, t0+dur) on the monotonic clock, parent
-    span name (or None at the root), nesting depth, originating thread and
-    free-form attrs (plus ``error`` when the body raised)."""
+    span name (or None at the root), nesting depth, originating thread,
+    `root` (sequence number of the outermost span it was opened under, its
+    own where it is the outermost) and free-form attrs (plus ``error`` when
+    the body raised)."""
 
-    __slots__ = ("name", "t0", "dur", "parent", "depth", "thread", "attrs")
+    __slots__ = ("name", "t0", "dur", "parent", "depth", "thread", "attrs",
+                 "root")
 
     def __init__(self, name: str, t0: float, dur: float,
-                 parent: str | None, depth: int, thread: int, attrs: dict):
+                 parent: str | None, depth: int, thread: int, attrs: dict,
+                 root: int = 0):
         self.name = name
         self.t0 = t0
         self.dur = dur
@@ -50,11 +62,13 @@ class SpanRecord:
         self.depth = depth
         self.thread = thread
         self.attrs = attrs
+        self.root = root
 
     def as_dict(self) -> dict:
         return {"name": self.name, "t0": self.t0, "dur": self.dur,
                 "parent": self.parent, "depth": self.depth,
-                "thread": self.thread, "attrs": self.attrs}
+                "thread": self.thread, "root": self.root,
+                "attrs": self.attrs}
 
     def __repr__(self) -> str:   # pragma: no cover - debugging aid
         return (f"SpanRecord({self.name!r}, dur={self.dur:.6f}, "
@@ -65,6 +79,9 @@ class _NullSpan:
     """Shared no-op context manager: the disabled-mode fast path."""
 
     __slots__ = ()
+    # a caller that would fetch extra data only for a span's attrs checks
+    # this first
+    live = False
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -78,11 +95,28 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# one sequence for the whole process: a root number is never reused, even
+# across tracers or threads (`next` on a count is atomic under the GIL)
+_ROOTS = itertools.count(1)
+
+_annotation = None
+
+
+def _trace_annotation(name: str):
+    """`jax.profiler.TraceAnnotation(name)`, imported on the first enabled
+    span so that disabled tracing never imports jax."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
 
 class _Span:
     """Active span handle; closes into a `SpanRecord` on exit."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_root", "_ann")
+    live = True
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -95,13 +129,20 @@ class _Span:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "_Span":
-        stack = self._tracer._stack()
+        tracer = self._tracer
+        stack = tracer._stack()
+        if not stack:
+            tracer._local.root = next(_ROOTS)
+        self._root = tracer._local.root
         stack.append(self.name)
+        self._ann = _trace_annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         # exception safety: pop our own frame even if the body replaced
         # the stack contents via nested tracer misuse
@@ -114,7 +155,7 @@ class _Span:
         parent = stack[-1] if stack else None
         self._tracer._record(SpanRecord(
             self.name, self._t0, dur, parent, len(stack),
-            threading.get_ident(), self.attrs))
+            threading.get_ident(), self.attrs, self._root))
         return False   # never swallow the exception
 
 
@@ -208,7 +249,8 @@ class Tracer:
             events.append({
                 "name": rec.name, "ph": "X", "pid": 0, "tid": tid,
                 "ts": rec.t0 * 1e6, "dur": rec.dur * 1e6,
-                "args": {**rec.attrs, "parent": rec.parent}})
+                "args": {**rec.attrs, "parent": rec.parent,
+                         "root": rec.root}})
         for ident, tid in threads.items():
             events.append({"name": "thread_name", "ph": "M", "pid": 0,
                            "tid": tid, "args": {"name": f"thread-{ident}"}})

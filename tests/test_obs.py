@@ -20,6 +20,8 @@ from conftest import gpt7b_job, one_circuit_topology
 # ------------------------------------------------------------------ metrics
 class TestMetrics:
     def test_counter_gauge_histogram_roundtrip(self):
+        """Counters and gauges (the registry has no histograms: none had
+        a producer)."""
         reg = MetricsRegistry(enabled=True)
         c = reg.counter("requests_total", "requests served")
         c.inc()
@@ -27,14 +29,9 @@ class TestMetrics:
         g = reg.gauge("pool_ports", "free ports")
         g.set(7)
         g.dec(3)
-        h = reg.histogram("latency_seconds", "op latency",
-                          buckets=(0.1, 1.0))
-        h.observe(0.05)
-        h.observe(0.5)
-        h.observe(5.0)
         assert c.value() == 1 and c.value(method="get") == 2
         assert g.value() == 4
-        assert h.value() == 3 and h.sum() == pytest.approx(5.55)
+        assert not hasattr(reg, "histogram")
 
     def test_counter_rejects_negative(self):
         reg = MetricsRegistry(enabled=True)
@@ -49,29 +46,18 @@ class TestMetrics:
 
     def test_prometheus_exposition_golden(self):
         """Exact text exposition: # HELP / # TYPE + one line per series,
-        labels sorted, histograms with cumulative le buckets."""
+        labels sorted, metrics by name."""
         reg = MetricsRegistry(enabled=True)
         c = reg.counter("events_total", "events handled")
         c.inc(3, kind="arrival")
         c.inc(1, kind="departure")
         g = reg.gauge("tenants", "admitted tenants")
         g.set(2)
-        h = reg.histogram("solve_seconds", "solver wall clock",
-                          buckets=(1.0, 10.0))
-        h.observe(0.5)
-        h.observe(4.0)
         assert reg.render_prometheus() == (
             "# HELP events_total events handled\n"
             "# TYPE events_total counter\n"
             'events_total{kind="arrival"} 3\n'
             'events_total{kind="departure"} 1\n'
-            "# HELP solve_seconds solver wall clock\n"
-            "# TYPE solve_seconds histogram\n"
-            'solve_seconds_bucket{le="1"} 1\n'
-            'solve_seconds_bucket{le="10"} 2\n'
-            'solve_seconds_bucket{le="+Inf"} 2\n'
-            "solve_seconds_sum 4.5\n"
-            "solve_seconds_count 2\n"
             "# HELP tenants admitted tenants\n"
             "# TYPE tenants gauge\n"
             "tenants 2\n")
@@ -167,6 +153,122 @@ class TestTracing:
             with tr.span("x"):
                 pass
         assert len(tr.records) == 2 and tr.dropped == 3
+
+
+    def test_root_is_shared_by_nested_spans_and_new_per_outermost(self):
+        tr = Tracer(enabled=True)
+        with tr.span("a"), tr.span("b"):
+            pass
+        with tr.span("c"):
+            pass
+        recs = {r.name: r for r in tr.records}
+        assert recs["a"].root == recs["b"].root != recs["c"].root
+        assert tr.records[0].as_dict()["root"] == recs["b"].root
+        args = [e["args"] for e in tr.to_chrome_trace()["traceEvents"]
+                if e["ph"] == "X"]
+        assert sorted(a["root"] for a in args) == sorted(
+            r.root for r in tr.records)
+
+
+# ------------------------------------------------------- spans of a plan
+@pytest.fixture
+def traced():
+    """The process tracer, on and empty for one test."""
+    from repro.obs import TRACER
+    TRACER.clear()
+    with TRACER.enabled(True):
+        yield TRACER
+    TRACER.clear()
+
+
+def _plan_tiny(dag, seed=0):
+    from repro.core.api import PlanRequest, plan
+    from repro.core.ga import GAOptions
+    opts = GAOptions(seed=seed, pop_size=8, max_generations=1, patience=1,
+                     time_limit=1e9, backend="jax")
+    return plan(PlanRequest(dag=dag, method="delta-fast", ga_options=opts))
+
+
+class TestPlanSpans:
+    def test_one_plan_root_per_request_shared_by_its_spans(self, traced,
+                                                           tiny_dag):
+        _plan_tiny(tiny_dag, seed=0)
+        _plan_tiny(tiny_dag, seed=1)
+        recs = traced.records
+        roots = [r for r in recs if r.name == "plan"]
+        assert len(roots) == 2 and all(r.depth == 0 for r in roots)
+        assert roots[0].attrs == {"kind": "dag", "method": "delta-fast"}
+        assert roots[0].root != roots[1].root
+        for root in roots:
+            inside = [r for r in recs if root.t0 <= r.t0
+                      and r.t0 + r.dur <= root.t0 + root.dur]
+            assert {r.root for r in inside} == {root.root}
+            assert {"des.exact", "des.prepare", "ga.evolve", "ga.rerank",
+                    "des.simulate"} <= {r.name for r in inside}
+        assert all(r.root in {x.root for x in roots} for r in recs)
+
+    def test_exact_spans_count_simulate_calls_and_their_events(
+            self, traced, tiny_dag, monkeypatch):
+        import repro.core.des as des
+        results, rounds = [], []
+        inner, fill = des._simulate, des._filled_rates
+
+        def counted(*args):
+            rounds.append(0)
+            out = inner(*args)
+            results.append(out[0])
+            return out
+
+        def counted_fill(*args):
+            out = fill(*args)
+            rounds[-1] += out[1]
+            return out
+
+        monkeypatch.setattr(des, "_simulate", counted)
+        monkeypatch.setattr(des, "_filled_rates", counted_fill)
+        _plan_tiny(tiny_dag)
+        exact = [r for r in traced.records if r.name == "des.exact"]
+        assert len(exact) == len(results) > 2
+        # the ideal first, the plan's own simulation last
+        assert exact[0].attrs["ideal"] and not exact[-1].attrs["ideal"]
+        for rec, res, r in zip(exact, results, rounds):
+            assert rec.attrs["n"] == tiny_dag.num_tasks
+            # each trip of the event loop but the last adds one event time
+            # to the first, t = 0
+            assert rec.attrs["events"] == len(res.events)
+            assert rec.attrs["rounds"] == r > 0
+        rerank = [r for r in traced.records if r.name == "ga.rerank"][0]
+        assert sum(r.parent == "ga.rerank" for r in exact) == \
+            rerank.attrs["candidates"] + 1
+
+    def test_batch_trips_are_lane_max_and_sum_results_unchanged(
+            self, tiny_dag):
+        from repro.core.des_jax import JaxDES
+        from repro.core.ga import TopologySpace
+        from repro.obs import TRACER
+        space = TopologySpace(tiny_dag)
+        genomes = space.random_init_batch(np.random.default_rng(3), 6)
+        genomes[0] = space.xbar          # the fastest lane
+        genomes[1] = space.g_min         # a slow one
+        jd = JaxDES(DESProblem(tiny_dag))
+        eu, ev = space.edge_u, space.edge_v
+        off = jd.batch_genome_makespan(genomes, eu, ev)
+        TRACER.clear()
+        with TRACER.enabled(True):
+            on = jd.batch_genome_makespan(genomes, eu, ev)
+        rec = [r for r in TRACER.records if r.name == "des.simulate"][-1]
+        TRACER.clear()
+        for a, b in zip(off, on):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+        ones = np.ones((jd.P, jd.P), dtype=np.float32)
+        _, _, trips = jd._compiled.batch_genomes(
+            jd._leaves, np.asarray(genomes), np.asarray(eu, np.int32),
+            np.asarray(ev, np.int32), ones)
+        trips = np.asarray(trips)
+        assert trips.shape == (6,) and (trips > 0).all()
+        assert rec.attrs["trips"] == trips.max()
+        assert rec.attrs["lane_trips"] == trips.sum()
+        assert rec.attrs["pop"] == 6
 
 
 # ----------------------------------------------------------------- timeline
