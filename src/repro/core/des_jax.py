@@ -14,7 +14,13 @@ acceleration argument only pays off when per-evaluation cost is flat):
   * the event loop advances to the next *distinct* event time each trip and
     retires every completion AND every start landing there in one step, so
     the trip count is bounded by distinct event times (<= 2n + eps), not by
-    a per-task event budget;
+    a per-task event budget.  Each trip's dependency readiness is one
+    masked max-plus pass over a dense (n, n) lag matrix built once per
+    simulation, streamed instead of scattered, when the padded shapes make
+    it the cheaper form (``n * n <= DENSE_DEPS_K * d``, `deps_layout`);
+    sparser DAGs keep the per-dependency scatter.  Either way an
+    unfinished predecessor's finish is INF, so a finite ready time alone
+    says every predecessor is done;
   * the inner max-min fair-share rounds run their fused (used, denom)
     reduction pair through `repro.kernels.waterfill` (Pallas on TPU, dense
     jnp `ref` oracle as the CPU/interpret fallback, the legacy segment-sum
@@ -65,6 +71,15 @@ _ENTRIES = get_gauge("des_compile_cache_entries",
                      "live compile-cache buckets")
 
 MAXMIN_BACKENDS = ("auto", "pallas", "ref", "segment")
+
+# Dependency readiness takes the dense (n, n) lag matrix when it holds at
+# most K times as many elements as the scatter over the d dependencies
+# touches: a TPU scatter retires its elements one after another, while the
+# dense masked max streams through the vector units.  On a TPU v5e at
+# population 48 (`python -m benchmarks.deps_layout`, PERF.md) the dense
+# pass wins every trip up to n*n/d = 128 at n = 256, 832 and 1216, and
+# loses at 256 for n = 256 and 832.
+DENSE_DEPS_K = 128
 
 
 # ------------------------------------------------------------------ options
@@ -170,7 +185,6 @@ class DESArrays(NamedTuple):
     dep_pre: jax.Array         # (d,)
     dep_succ: jax.Array        # (d,)
     dep_delta: jax.Array       # (d,)
-    indegree: jax.Array        # (n,)
     con_task: jax.Array        # (e,) incidence: task index
     con_id: jax.Array          # (e,) incidence: constraint index
     con_w: jax.Array           # (e,) weight on phi (F_m for links, 1 for NIC)
@@ -235,7 +249,6 @@ def _problem_fields(p: DESProblem, pad: PadSpec) -> dict[str, np.ndarray]:
         "dep_pre": _pad_to(p.dep_pre.astype(np.int32), pad.d, 0),
         "dep_succ": _pad_to(p.dep_succ.astype(np.int32), pad.d, 0),
         "dep_delta": _pad_to(p.dep_delta, pad.d, 0.0),
-        "indegree": _pad_to(p.indegree.astype(np.int32), pad.n, 0),
         "con_task": _pad_to(p.con_task.astype(np.int32), pad.e, 0),
         "con_id": _pad_to(con_id.astype(np.int32), pad.e, 0),
         "con_w": _pad_to(p.con_w, pad.e, 0.0),
@@ -310,6 +323,39 @@ def _maxmin(arr: DESArrays, active: jax.Array, caps: jax.Array,
 
 
 # --------------------------------------------------------------- event loop
+def deps_layout(n: int, d: int) -> str:
+    """'dense' or 'scatter': how `_simulate` computes dependency readiness
+    for `n` (padded) tasks and `d` (padded) dependencies."""
+    return "dense" if n * n <= DENSE_DEPS_K * d else "scatter"
+
+
+def _ready_fn(arr: DESArrays, deps: str):
+    """``finish -> ready``: each task's earliest start, the largest
+    ``finish[pre] + delta`` over its dependencies (0 with none).
+
+    'dense' scatters the lags once into an (n, n) matrix, -INF where no
+    dependency runs (the largest delta of duplicates), and reduces over
+    the predecessor axis each call, so the successors stay on the lanes.
+    The mask is needed: an unfinished predecessor's INF finish plus a
+    finite -1e30 stays INF in float32, and INF + -INF is NaN.  Both forms
+    add the same float32 pairs and take an exact max, so they agree bit
+    for bit."""
+    n = arr.n
+    if deps == "dense":
+        lags = jnp.full((n, n), -INF, dtype=arr.dep_delta.dtype).at[
+            arr.dep_pre, arr.dep_succ].max(arr.dep_delta)
+
+        def ready(finish):
+            lag = jnp.where(lags > -INF, finish[:, None] + lags, -INF)
+            return jnp.maximum(jnp.max(lag, axis=0), 0.0)
+        return ready
+
+    def ready(finish):
+        return jnp.zeros(n).at[arr.dep_succ].max(
+            finish[arr.dep_pre] + arr.dep_delta)
+    return ready
+
+
 class _StaticCfg(NamedTuple):
     """Hashable trace-static DES configuration (one compile bucket)."""
     n: int
@@ -320,13 +366,14 @@ class _StaticCfg(NamedTuple):
     backend: str
     interpret: bool
     members: int            # 0 = single problem, M = stacked ensemble
+    deps: str               # readiness layout (`deps_layout`)
 
 
 def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
-              mask: jax.Array, max_events: int, backend: str = "segment",
-              interpret: bool = False) -> tuple[jax.Array, jax.Array,
-                                                jax.Array, jax.Array,
-                                                jax.Array]:
+              mask: jax.Array, max_events: int, deps: str,
+              backend: str = "segment", interpret: bool = False
+              ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
+                         jax.Array]:
     """Returns (makespan, feasible, start, finish, trips), `trips` being
     the event loop's trip count.
 
@@ -343,6 +390,9 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     link capacities only -- NIC caps are pod-local and unaffected -- and is
     a *traced* operand, so pricing a failure never leaves the compile
     bucket the healthy plan was jitted into.
+
+    ``deps`` is the readiness layout (`_ready_fn`), which `deps_layout`
+    picks from the padded shapes.
     """
     n = arr.n
     B = arr.nic_bandwidth
@@ -357,6 +407,7 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     # dense incidence for the kernel backends, built once per simulation
     # (one scatter) and reused by every fair-share round of every event
     W = _dense_incidence(arr) if backend != "segment" else None
+    ready_at = _ready_fn(arr, deps)
 
     eps = 1e-6 if arr.volume.dtype == jnp.float32 else 1e-12
     veps = 1e-5 if arr.volume.dtype == jnp.float32 else 1e-9
@@ -364,12 +415,11 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     # complete too -- otherwise `t + dt == t` stalls the simulation
     teps = 1e-5 if arr.volume.dtype == jnp.float32 else 1e-12
 
-    def retire_starts(t_now, started, finish, missing):
+    def retire_starts(t_now, started, finish):
         """Start every pending task whose ready time has arrived at
-        `t_now`; returns the next pending ready time as well."""
-        lag = finish[arr.dep_pre] + arr.dep_delta
-        ready = jnp.zeros(n).at[arr.dep_succ].max(lag)
-        ready = jnp.where((missing == 0) & ~started, ready, INF)
+        `t_now`; returns the next pending ready time as well.  A task
+        with an unfinished predecessor reads INF (that finish is INF)."""
+        ready = jnp.where(started, INF, ready_at(finish))
         newly = ready <= t_now * (1 + eps) + eps * 1e-3
         t_ready = jnp.min(jnp.where(newly, INF, ready))
         return started | newly, newly, ready, t_ready
@@ -383,11 +433,8 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     done = started
     start = jnp.where(started, 0.0, INF)
     finish = start
-    missing = arr.indegree - jax.ops.segment_sum(
-        (arr.dep_pre == 0).astype(jnp.int32), arr.dep_succ, num_segments=n)
     # retire the t=0 start events before the loop
-    started, newly, ready, t_ready = retire_starts(0.0, started, finish,
-                                                   missing)
+    started, newly, ready, t_ready = retire_starts(0.0, started, finish)
     start = jnp.where(newly, ready, start)
     feasible = jnp.array(True)
 
@@ -396,8 +443,7 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
         return (i < max_events) & jnp.isfinite(t) & feasible
 
     def body(state):
-        (i, t, t_ready, rem, started, done, start, finish, missing,
-         feasible) = state
+        i, t, t_ready, rem, started, done, start, finish, feasible = state
         active = started & ~done
         rates = _maxmin(arr, active, caps, backend, interpret, W)
         feasible = feasible & jnp.all(jnp.where(active, rates > 0, True))
@@ -414,23 +460,20 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
             | (dt_rem <= teps * jnp.maximum(t_next, 1e-9)))
         finish = jnp.where(newdone, t_next, finish)
         done = done | newdone
-        missing = missing - jax.ops.segment_sum(
-            newdone[arr.dep_pre].astype(jnp.int32), arr.dep_succ,
-            num_segments=n)
         # retire the start events at t_next in the same trip (readiness
-        # recomputed against the post-completion finish/missing state)
+        # recomputed against the post-completion finish state)
         started, newly, ready, t_ready = retire_starts(t_next, started,
-                                                       finish, missing)
+                                                       finish)
         start = jnp.where(newly, ready, start)
         all_done = done.all()
         t_out = jnp.where(all_done, -INF, t_next)  # exit condition
         return (i + 1, t_out, t_ready, rem, started, done, start, finish,
-                missing, feasible)
+                feasible)
 
     state = (0, jnp.array(0.0), t_ready, rem, started, done, start, finish,
-             missing, feasible)
+             feasible)
     state = jax.lax.while_loop(cond, body, state)
-    trips, _, _, _, _, done, start, finish, _, feasible = state
+    trips, _, _, _, _, done, start, finish, feasible = state
     feasible = feasible & done.all()
     makespan = jnp.where(feasible, jnp.max(jnp.where(jnp.isfinite(finish),
                                                      finish, -INF)), INF)
@@ -465,7 +508,8 @@ class CompiledDES:
     def _run(self, leaves, x, ideal, mask):
         cfg = self.cfg
         return _simulate(self._rebuild(leaves), x, ideal, mask,
-                         cfg.max_events, cfg.backend, cfg.interpret)
+                         cfg.max_events, cfg.deps, cfg.backend,
+                         cfg.interpret)
 
     def _scatter(self, g, eu, ev):
         P = self.cfg.P
@@ -485,7 +529,8 @@ class CompiledDES:
             if state["first"]:
                 state["first"] = False
                 with span("des.jit", entry=entry, n=cfg.n,
-                          members=cfg.members, backend=cfg.backend):
+                          members=cfg.members, backend=cfg.backend,
+                          deps=cfg.deps):
                     return fn(*args)
             return fn(*args)
         return wrapper
@@ -624,11 +669,12 @@ class JaxDES:
                              P=problem.dag.cluster.num_pods,
                              max_events=self.max_events,
                              backend=ropt.backend,
-                             interpret=ropt.interpret, members=0)
+                             interpret=ropt.interpret, members=0,
+                             deps=deps_layout(pad.n, pad.d))
             self._compiled, hit = _compiled_for(cfg, pad, ropt.warn_on_miss)
             self._leaves = tuple(getattr(self.arrays, f)
                                  for f in _ARRAY_FIELDS)
-            sp.set(n=pad.n, hit=hit)
+            sp.set(n=pad.n, hit=hit, deps=cfg.deps)
         self.P = problem.dag.cluster.num_pods
 
     def _mask(self, mask) -> jax.Array:
@@ -770,11 +816,12 @@ class EnsembleJaxDES:
                              max_events=self.max_events,
                              backend=ropt.backend,
                              interpret=ropt.interpret,
-                             members=len(problems))
+                             members=len(problems),
+                             deps=deps_layout(pad.n, pad.d))
             self._compiled, hit = _compiled_for(cfg, pad, ropt.warn_on_miss)
             self._leaves = tuple(getattr(self.arrays, f)
                                  for f in _ARRAY_FIELDS)
-            sp.set(n=pad.n, hit=hit)
+            sp.set(n=pad.n, hit=hit, deps=cfg.deps)
 
     def _masks(self, masks) -> jax.Array:
         """(M, P, P) per-member availability factors (ones when healthy).
