@@ -16,9 +16,7 @@ acceleration argument only pays off when per-evaluation cost is flat):
     the trip count is bounded by distinct event times (<= 2n + eps), not by
     a per-task event budget.  Each trip's dependency readiness is one
     masked max-plus pass over a dense (n, n) lag matrix built once per
-    simulation, streamed instead of scattered, when the padded shapes make
-    it the cheaper form (``n * n <= DENSE_DEPS_K * d``, `deps_layout`);
-    sparser DAGs keep the per-dependency scatter.  Either way an
+    simulation, streamed instead of scattered over the d dependencies; an
     unfinished predecessor's finish is INF, so a finite ready time alone
     says every predecessor is done;
   * the inner max-min fair-share rounds run their fused (used, denom)
@@ -71,15 +69,6 @@ _ENTRIES = get_gauge("des_compile_cache_entries",
                      "live compile-cache buckets")
 
 MAXMIN_BACKENDS = ("auto", "pallas", "ref", "segment")
-
-# Dependency readiness takes the dense (n, n) lag matrix when it holds at
-# most K times as many elements as the scatter over the d dependencies
-# touches: a TPU scatter retires its elements one after another, while the
-# dense masked max streams through the vector units.  On a TPU v5e at
-# population 48 (`python -m benchmarks.deps_layout`, PERF.md) the dense
-# pass wins every trip up to n*n/d = 128 at n = 256, 832 and 1216, and
-# loses at 256 for n = 256 and 832.
-DENSE_DEPS_K = 128
 
 
 # ------------------------------------------------------------------ options
@@ -323,36 +312,23 @@ def _maxmin(arr: DESArrays, active: jax.Array, caps: jax.Array,
 
 
 # --------------------------------------------------------------- event loop
-def deps_layout(n: int, d: int) -> str:
-    """'dense' or 'scatter': how `_simulate` computes dependency readiness
-    for `n` (padded) tasks and `d` (padded) dependencies."""
-    return "dense" if n * n <= DENSE_DEPS_K * d else "scatter"
-
-
-def _ready_fn(arr: DESArrays, deps: str):
+def _ready_fn(arr: DESArrays):
     """``finish -> ready``: each task's earliest start, the largest
     ``finish[pre] + delta`` over its dependencies (0 with none).
 
-    'dense' scatters the lags once into an (n, n) matrix, -INF where no
-    dependency runs (the largest delta of duplicates), and reduces over
-    the predecessor axis each call, so the successors stay on the lanes.
-    The mask is needed: an unfinished predecessor's INF finish plus a
-    finite -1e30 stays INF in float32, and INF + -INF is NaN.  Both forms
-    add the same float32 pairs and take an exact max, so they agree bit
-    for bit."""
-    n = arr.n
-    if deps == "dense":
-        lags = jnp.full((n, n), -INF, dtype=arr.dep_delta.dtype).at[
-            arr.dep_pre, arr.dep_succ].max(arr.dep_delta)
-
-        def ready(finish):
-            lag = jnp.where(lags > -INF, finish[:, None] + lags, -INF)
-            return jnp.maximum(jnp.max(lag, axis=0), 0.0)
-        return ready
+    The lags are scattered once into a dense (n, n) matrix, -INF where no
+    dependency runs (the largest delta of duplicates), and each call
+    reduces over the predecessor axis, so the successors stay on the
+    lanes: a TPU scatter over the d dependencies retires its elements one
+    after another, while this masked max streams through the vector
+    units.  The mask is needed: an unfinished predecessor's INF finish
+    plus a finite -1e30 stays INF in float32, and INF + -INF is NaN."""
+    lags = jnp.full((arr.n, arr.n), -INF, dtype=arr.dep_delta.dtype).at[
+        arr.dep_pre, arr.dep_succ].max(arr.dep_delta)
 
     def ready(finish):
-        return jnp.zeros(n).at[arr.dep_succ].max(
-            finish[arr.dep_pre] + arr.dep_delta)
+        lag = jnp.where(lags > -INF, finish[:, None] + lags, -INF)
+        return jnp.maximum(jnp.max(lag, axis=0), 0.0)
     return ready
 
 
@@ -366,14 +342,13 @@ class _StaticCfg(NamedTuple):
     backend: str
     interpret: bool
     members: int            # 0 = single problem, M = stacked ensemble
-    deps: str               # readiness layout (`deps_layout`)
 
 
 def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
-              mask: jax.Array, max_events: int, deps: str,
-              backend: str = "segment", interpret: bool = False
-              ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
-                         jax.Array]:
+              mask: jax.Array, max_events: int, backend: str = "segment",
+              interpret: bool = False) -> tuple[jax.Array, jax.Array,
+                                                jax.Array, jax.Array,
+                                                jax.Array]:
     """Returns (makespan, feasible, start, finish, trips), `trips` being
     the event loop's trip count.
 
@@ -390,9 +365,6 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     link capacities only -- NIC caps are pod-local and unaffected -- and is
     a *traced* operand, so pricing a failure never leaves the compile
     bucket the healthy plan was jitted into.
-
-    ``deps`` is the readiness layout (`_ready_fn`), which `deps_layout`
-    picks from the padded shapes.
     """
     n = arr.n
     B = arr.nic_bandwidth
@@ -407,7 +379,7 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     # dense incidence for the kernel backends, built once per simulation
     # (one scatter) and reused by every fair-share round of every event
     W = _dense_incidence(arr) if backend != "segment" else None
-    ready_at = _ready_fn(arr, deps)
+    ready_at = _ready_fn(arr)
 
     eps = 1e-6 if arr.volume.dtype == jnp.float32 else 1e-12
     veps = 1e-5 if arr.volume.dtype == jnp.float32 else 1e-9
@@ -508,8 +480,7 @@ class CompiledDES:
     def _run(self, leaves, x, ideal, mask):
         cfg = self.cfg
         return _simulate(self._rebuild(leaves), x, ideal, mask,
-                         cfg.max_events, cfg.deps, cfg.backend,
-                         cfg.interpret)
+                         cfg.max_events, cfg.backend, cfg.interpret)
 
     def _scatter(self, g, eu, ev):
         P = self.cfg.P
@@ -529,8 +500,7 @@ class CompiledDES:
             if state["first"]:
                 state["first"] = False
                 with span("des.jit", entry=entry, n=cfg.n,
-                          members=cfg.members, backend=cfg.backend,
-                          deps=cfg.deps):
+                          members=cfg.members, backend=cfg.backend):
                     return fn(*args)
             return fn(*args)
         return wrapper
@@ -669,12 +639,11 @@ class JaxDES:
                              P=problem.dag.cluster.num_pods,
                              max_events=self.max_events,
                              backend=ropt.backend,
-                             interpret=ropt.interpret, members=0,
-                             deps=deps_layout(pad.n, pad.d))
+                             interpret=ropt.interpret, members=0)
             self._compiled, hit = _compiled_for(cfg, pad, ropt.warn_on_miss)
             self._leaves = tuple(getattr(self.arrays, f)
                                  for f in _ARRAY_FIELDS)
-            sp.set(n=pad.n, hit=hit, deps=cfg.deps)
+            sp.set(n=pad.n, hit=hit)
         self.P = problem.dag.cluster.num_pods
 
     def _mask(self, mask) -> jax.Array:
@@ -816,12 +785,11 @@ class EnsembleJaxDES:
                              max_events=self.max_events,
                              backend=ropt.backend,
                              interpret=ropt.interpret,
-                             members=len(problems),
-                             deps=deps_layout(pad.n, pad.d))
+                             members=len(problems))
             self._compiled, hit = _compiled_for(cfg, pad, ropt.warn_on_miss)
             self._leaves = tuple(getattr(self.arrays, f)
                                  for f in _ARRAY_FIELDS)
-            sp.set(n=pad.n, hit=hit, deps=cfg.deps)
+            sp.set(n=pad.n, hit=hit)
 
     def _masks(self, masks) -> jax.Array:
         """(M, P, P) per-member availability factors (ones when healthy).

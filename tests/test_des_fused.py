@@ -1,7 +1,7 @@
 """Kernel-fused DES: waterfill-backend parity (segment / ref / pallas
 interpret) against a pure-numpy max-min reference, bucket-padding
 equivalence, the module-level compile cache, batched ensemble trimming,
-and the dense and scatter layouts of dependency readiness."""
+and the dense max-plus dependency readiness."""
 import logging
 
 import numpy as np
@@ -11,14 +11,14 @@ from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp
-from conftest import gpt7b_job, one_circuit_topology, random_comm_dags
+from conftest import gpt7b_job, one_circuit_topology
 from repro.core import des_jax
 from repro.core.cluster import ClusterSpec
 from repro.core.dag import CommDAG, CommTask, DagEnsemble, Dep, make_virtual
 from repro.core.des import DESProblem, simulate
 from repro.core.des_jax import (DESArrays, DESOptions, EnsembleJaxDES,
                                 JaxDES, PadSpec, _maxmin, des_cache_clear,
-                                des_cache_stats, deps_layout)
+                                des_cache_stats)
 from repro.core.ga import trim_ports_ensemble
 from repro.core.schedule import build_comm_dag
 
@@ -329,29 +329,7 @@ def test_fleet_robust_refs_come_from_plan_cache():
     assert fp.tenants["j"].plan.details["ref_cache_hits"] == 2
 
 
-# ------------------------------------------ dependency readiness layouts
-# DENSE_DEPS_K forcing each layout: n * n <= K * d always / never holds
-FORCE_K = {"dense": 1 << 40, "scatter": 0}
-
-
-def _forced(monkeypatch, layout, make):
-    """Build a simulator with the readiness layout forced (the layout is
-    part of the compile-bucket key, so each gets its own executable)."""
-    monkeypatch.setattr(des_jax, "DENSE_DEPS_K", FORCE_K[layout])
-    sim = make()
-    assert sim._compiled.cfg.deps == layout
-    return sim
-
-
-def _single_run(monkeypatch, layout, prob, x, ideal=False, mask=None,
-                bucket=True):
-    jd = _forced(monkeypatch, layout, lambda: JaxDES(
-        prob, options=DESOptions(backend="ref", bucket=bucket)))
-    out = jd._compiled.single(jd._leaves, jnp.asarray(x), jnp.asarray(ideal),
-                              jd._mask(mask))
-    return jd, [np.asarray(o) for o in out]
-
-
+# ----------------------------------------- dense dependency readiness
 def _dark_first_pair(dag):
     P = dag.cluster.num_pods
     mask = np.ones((P, P), dtype=np.float32)
@@ -360,8 +338,20 @@ def _dark_first_pair(dag):
     return mask
 
 
-LAYOUT_CASES = {
-    # bucket padding: ghost tasks and ghost deps on both paths
+def _assert_matches_oracle(prob, x, ms, feas, start, finish, ideal=False):
+    """The device loop against the float64 numpy DES: feasibility, the
+    makespan and every task's start and finish."""
+    want = simulate(prob, x, ideal=ideal)
+    assert bool(feas) == want.feasible
+    if want.feasible:
+        assert float(ms) == pytest.approx(want.makespan, rel=RTOL)
+        np.testing.assert_allclose(start, want.start, rtol=RTOL, atol=1e-9)
+        np.testing.assert_allclose(finish, want.finish, rtol=RTOL,
+                                   atol=1e-9)
+
+
+READINESS_CASES = {
+    # bucket padding: ghost tasks and ghost deps in the lag matrix
     "bucketed": dict(mb=2),
     "exact-shape": dict(mb=3, bucket=False),
     "ideal": dict(mb=2, ideal=True),
@@ -370,46 +360,36 @@ LAYOUT_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", list(LAYOUT_CASES))
-def test_dense_and_scatter_readiness_agree_bit_for_bit(case, monkeypatch):
-    """The dense (n, n) readiness pass and the per-dependency scatter give
-    the same makespan, feasibility, start, finish and trip count, bit for
-    bit: the same float32 adds, and an exact max."""
-    c = LAYOUT_CASES[case]
+@pytest.mark.parametrize("case", list(READINESS_CASES))
+def test_dense_readiness_matches_the_float64_oracle(case):
+    """The event loop with the dense max-plus readiness pass reproduces the
+    numpy DES: makespan, feasibility, start and finish; a dark link on a
+    pair that carries traffic comes out infeasible."""
+    c = READINESS_CASES[case]
     dag = build_comm_dag(gpt7b_job(c["mb"]))
     prob = DESProblem(dag)
     x = one_circuit_topology(dag) * c.get("scale", 1)
-    mask = _dark_first_pair(dag) if c.get("dark") else None
-    runs = {}
-    for layout in FORCE_K:
-        jd, runs[layout] = _single_run(
-            monkeypatch, layout, prob, x, ideal=c.get("ideal", False),
-            mask=mask, bucket=c.get("bucket", True))
+    ideal = c.get("ideal", False)
+    jd = JaxDES(prob, options=DESOptions(backend="ref",
+                                         bucket=c.get("bucket", True)))
     if c.get("bucket", True):
         assert jd.pad.n > prob.n and jd.pad.d > len(prob.dep_pre)
-    for a, b in zip(runs["dense"], runs["scatter"]):
-        np.testing.assert_array_equal(a, b)
-    ms, feas = runs["dense"][:2]
-    assert bool(feas) == (not c.get("dark"))
-    if feas:
-        want = simulate(prob, x, ideal=c.get("ideal", False)).makespan
-        assert float(ms) == pytest.approx(want, rel=RTOL)
+    mask = _dark_first_pair(dag) if c.get("dark") else None
+    ms, feas, start, finish, trips = [
+        np.asarray(o) for o in jd._compiled.single(
+            jd._leaves, jnp.asarray(x), jnp.asarray(ideal), jd._mask(mask))]
+    assert trips > 0
+    if c.get("dark"):
+        assert not feas and ms == np.inf
+        return
+    n = prob.n
+    _assert_matches_oracle(prob, x, ms, feas, start[:n], finish[:n],
+                           ideal=ideal)
 
 
-@settings(max_examples=8, deadline=None)
-@given(random_comm_dags(max_pods=4, max_tasks=12))
-def test_property_dense_and_scatter_readiness_agree(dag):
-    prob = DESProblem(dag)
-    x = one_circuit_topology(dag)
-    with pytest.MonkeyPatch.context() as mp:
-        runs = [_single_run(mp, layout, prob, x)[1] for layout in FORCE_K]
-    for a, b in zip(*runs):
-        np.testing.assert_array_equal(a, b)
-
-
-def test_dense_and_scatter_agree_on_ensemble_genomes(dag, monkeypatch):
+def test_dense_readiness_on_ensemble_genomes(dag):
     """One members x genomes call (two stacked DAGs of different sizes,
-    one of them with a dark link) comes out the same on both layouts."""
+    one of them with a dark link) matches the oracle member by member."""
     probs = [DESProblem(dag), DESProblem(build_comm_dag(gpt7b_job(3)))]
     pairs = sorted(set(dag.undirected_pairs())
                    | set(probs[1].dag.undirected_pairs()))
@@ -419,52 +399,57 @@ def test_dense_and_scatter_agree_on_ensemble_genomes(dag, monkeypatch):
     P = dag.cluster.num_pods
     masks = np.ones((2, P, P), dtype=np.float32)
     masks[1] = _dark_first_pair(probs[1].dag)
-    outs = {}
-    for layout in FORCE_K:
-        ens = _forced(monkeypatch, layout, lambda: EnsembleJaxDES(
-            probs, options=DESOptions(backend="ref")))
-        outs[layout] = [np.asarray(o) for o in ens._compiled.ensemble_genomes(
-            ens._leaves, jnp.asarray(genomes), jnp.asarray(eu),
-            jnp.asarray(ev), jnp.asarray(masks))]
-    for a, b in zip(outs["dense"], outs["scatter"]):
-        np.testing.assert_array_equal(a, b)
-    ms, feas, trips = outs["dense"]
+    ens = EnsembleJaxDES(probs, options=DESOptions(backend="ref"))
+    ms, feas, trips = [np.asarray(o) for o in ens._compiled.ensemble_genomes(
+        ens._leaves, jnp.asarray(genomes), jnp.asarray(eu), jnp.asarray(ev),
+        jnp.asarray(masks))]
     assert ms.shape == (5, 2) and feas[:, 0].all() and not feas[:, 1].any()
     assert (trips > 0).all()
+    for g, genome in enumerate(genomes):
+        x = np.zeros((P, P), dtype=np.int64)
+        x[eu, ev] = x[ev, eu] = genome
+        want = simulate(probs[0], x).makespan
+        assert ms[g, 0] == pytest.approx(want, rel=RTOL)
 
 
-def test_deps_layout_follows_the_padded_shapes(dag):
-    """Dense for a dense DAG, scatter for a chain-like one; the simulator
-    records its choice on the `des.prepare` and `des.jit` spans."""
-    from repro.obs import TRACER
-    K = des_jax.DENSE_DEPS_K
-    # the megatron-462b and -177b buckets (n * n / d about 7.9 and 8.5)
-    assert deps_layout(832, 88128) == "dense"
-    assert deps_layout(256, 7744) == "dense"
-    # a chain near the JAX task limit: about 2 dependencies a task
-    assert deps_layout(1216, 2 * 1216) == "scatter"
-    assert deps_layout(64, 64 * 64 // K) == "dense"
-    assert deps_layout(64, 64 * 64 // K - 1) == "scatter"
-    prob = DESProblem(dag)
-    des_cache_clear()
-    TRACER.clear()
-    with TRACER.enabled(True):
-        jd = JaxDES(prob, options=DESOptions(backend="ref"))
-        jd.makespan(one_circuit_topology(dag))
-    recs = {r.name: r for r in TRACER.records}
-    TRACER.clear()
-    want = deps_layout(jd.pad.n, jd.pad.d)
-    assert jd._compiled.cfg.deps == want
-    assert recs["des.prepare"].attrs["deps"] == want
-    assert recs["des.jit"].attrs["deps"] == want
+def _scatter_ready(pre, succ, delta, finish):
+    """Float32 reference readiness, one dependency at a time."""
+    ready = np.zeros(len(finish), dtype=np.float32)
+    np.maximum.at(ready, succ, (finish[pre] + delta).astype(np.float32))
+    return ready
+
+
+def test_dense_readiness_takes_the_largest_lag_of_duplicate_deps():
+    """Duplicate (pre, succ) pairs and ghost deps (0 -> 0, delta 0) land in
+    one cell of the lag matrix, which keeps the largest delta; readiness
+    equals the per-dependency float32 max bit for bit."""
+    pre = np.array([0, 1, 1, 1, 2, 0, 0], dtype=np.int32)
+    succ = np.array([1, 3, 3, 2, 3, 2, 0], dtype=np.int32)
+    delta = np.array([0.5, 0.25, 2.0, 1.0, 0.125, 3.0, 0.0],
+                     dtype=np.float32)
+    arr = _synthetic_arrays(4, 1, np.zeros(1), np.zeros(1), np.ones(1),
+                            np.ones(4))._replace(
+        dep_pre=jnp.asarray(pre), dep_succ=jnp.asarray(succ),
+        dep_delta=jnp.asarray(delta))
+    ready_at = jax.jit(des_jax._ready_fn(arr))
+    for finish in ([0.0, 1.5, np.inf, np.inf], [0.0, 1.5, 4.0, np.inf],
+                   [0.0, 1.0 / 3.0, 7.1, 9.0]):
+        finish = np.asarray(finish, dtype=np.float32)
+        np.testing.assert_array_equal(np.asarray(ready_at(finish)),
+                                      _scatter_ready(pre, succ, delta,
+                                                     finish))
+    got = np.asarray(ready_at(np.array([0.0, 1.5, 4.0, np.inf],
+                                       dtype=np.float32)))
+    assert got[1] == 0.5 and got[2] == 3.0 and got[3] == np.float32(4.125)
 
 
 def test_finite_ready_time_is_the_missing_count_gate():
     """With no `missing` counter, a task counts as having every
     predecessor done when its ready time is finite.  Replay a reference
     run: at each of its completion times the done set is the tasks that
-    have finished by then, and at every such state `ready < INF` equals
-    `missing == 0` for every task, on both layouts."""
+    have finished by then, and at every such state the dense readiness
+    equals the per-dependency float32 max bit for bit, and `ready < INF`
+    equals `missing == 0` for every task."""
     dag = build_comm_dag(gpt7b_job(4))
     jd = JaxDES(DESProblem(dag), options=DESOptions(backend="ref"))
     arr = jd.arrays
@@ -473,15 +458,16 @@ def test_finite_ready_time_is_the_missing_count_gate():
         jd._leaves, jnp.asarray(x), jnp.asarray(False), jd._mask(None))]
     assert feas and trips > 0 and np.isfinite(finish).all()
     pre, succ = np.asarray(arr.dep_pre), np.asarray(arr.dep_succ)
+    delta = np.asarray(arr.dep_delta)
     indeg = np.bincount(succ, minlength=arr.n)
-    ready_fns = {lay: jax.jit(des_jax._ready_fn(arr, lay))
-                 for lay in FORCE_K}
+    ready_at = jax.jit(des_jax._ready_fn(arr))
     times = np.unique(finish)
     assert len(times) > 10
     for t in times:
         done = finish <= t
         missing = indeg - np.bincount(succ[done[pre]], minlength=arr.n)
-        fin_t = jnp.asarray(np.where(done, finish, np.inf))
-        ready = {lay: np.asarray(f(fin_t)) for lay, f in ready_fns.items()}
-        np.testing.assert_array_equal(ready["dense"], ready["scatter"])
-        np.testing.assert_array_equal(ready["dense"] < np.inf, missing == 0)
+        fin_t = np.where(done, finish, np.inf).astype(np.float32)
+        ready = np.asarray(ready_at(fin_t))
+        np.testing.assert_array_equal(ready,
+                                      _scatter_ready(pre, succ, delta, fin_t))
+        np.testing.assert_array_equal(ready < np.inf, missing == 0)

@@ -22,6 +22,8 @@ def test_property_matches_numpy(dag):
     assert feas == r.feasible
     if r.feasible:
         assert ms == pytest.approx(r.makespan, rel=RTOL)
+        np.testing.assert_allclose(start, r.start, rtol=RTOL, atol=1e-9)
+        np.testing.assert_allclose(finish, r.finish, rtol=RTOL, atol=1e-9)
 
 
 def test_gpt7b_grid_matches_numpy():

@@ -24,7 +24,7 @@ from repro.configs import PAPER_WORKLOADS, make_job
 from repro.core.des import DESProblem
 from repro.core.des_jax import (_ARRAY_FIELDS, CompiledDES, DESArrays,
                                 DESOptions, PadSpec, _StaticCfg,
-                                default_max_events, deps_layout)
+                                default_max_events)
 from repro.core.schedule import build_comm_dag
 from repro.kernels import ops
 
@@ -94,8 +94,8 @@ def test_maxplus_compiles(compile_v5e):
 def test_fused_batch_genomes_step_compiles(compile_v5e):
     """The GA's fitness step (genome scatter + vmapped DES with the
     fill_round kernel) at megatron-462b's Table I bucket, population 48.
-    Its dependency readiness takes the dense layout, and the masked
-    max-plus pass stays fused: no (48, n, n) buffer is written."""
+    Its masked max-plus dependency readiness stays fused: no (48, n, n)
+    buffer is written."""
     arch = PAPER_WORKLOADS["megatron-462b"]
     dag = build_comm_dag(make_job(arch, seq_len=4096,
                                   microbatches=arch.plan.num_microbatches),
@@ -109,8 +109,7 @@ def test_fused_batch_genomes_step_compiles(compile_v5e):
     cd = CompiledDES(_StaticCfg(
         n=pad.n, num_cons=pad.cons, num_link_cons=pad.links, P=P,
         max_events=default_max_events(pad.n), backend=ropt.backend,
-        interpret=ropt.interpret, members=0,
-        deps=deps_layout(pad.n, pad.d)))
+        interpret=ropt.interpret, members=0))
     arrays = DESArrays.from_problem(problem, pad)
     leaves = tuple(getattr(arrays, f) for f in _ARRAY_FIELDS)
     E = len(dag.undirected_pairs())
@@ -129,5 +128,4 @@ def test_fused_batch_genomes_step_compiles(compile_v5e):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
-    assert cd.cfg.deps == "dense"
     assert mem.temp_size_in_bytes < 48 * pad.n * pad.n * 4 // 8
