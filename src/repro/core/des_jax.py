@@ -366,7 +366,6 @@ def _simulate(arr: DESArrays, x: jax.Array, ideal_flag: jax.Array,
     a *traced* operand, so pricing a failure never leaves the compile
     bucket the healthy plan was jitted into.
     """
-    n = arr.n
     B = arr.nic_bandwidth
     # cap dtype follows the simulation dtype: hard-coding float64 is a
     # silent no-op downcast to float32 under default x64-disabled jax
