@@ -28,7 +28,7 @@ WORKLOADS = ("gpt-7b", "megatron-177b", "mixtral-8x22b", "megatron-462b",
 # MILP variants run on the tractable subset by default.  mixtral-8x22b used
 # to be here, but that was an artifact of the bug this repo fixed: its DAG
 # silently dropped the expert-parallel all-to-all and carried only 16 DP
-# tasks.  The corrected MoE DAG (272 tasks at reduced scale) needs
+# tasks.  The corrected MoE DAG (912 tasks at reduced scale) needs
 # Gurobi-class budgets, so only gpt-7b stays HiGHS-tractable by default;
 # delta-fast covers the MoE workloads.
 MILP_WORKLOADS = ("gpt-7b",)

@@ -36,13 +36,26 @@ MEGATRON_462B = ArchSpec(
                     microbatches=128),
     source="paper Table I / Megatron benchmarks [59-61]")
 
+# DeepSeek-V3 at its published widths (huggingface.co/deepseek-ai/
+# DeepSeek-V3 config.json): MLA, 3 dense layers then 58 MoE layers of 256
+# routed experts (top 8 within 4 of 8 groups) plus one shared expert, and
+# one MTP module.  The 61 layers split 4 per stage over stages 0-14 and 1
+# on stage 15, which also holds the head and the MTP module: the split
+# with the smallest largest stage (4 MoE layers, 2.34B active parameters).
+# FP8 dispatch and BF16 combine, as in the DeepSeek-V3 report
+# (arXiv:2412.19437).
 DEEPSEEK_671B = ArchSpec(
-    ModelConfig(name="deepseek-671b", family="moe", layers=64,
-                d_model=7168, heads=56, kv_heads=8, d_ff=1888,
-                vocab=129280, moe_experts=256, moe_top_k=8, moe_every=1),
+    ModelConfig(name="deepseek-671b", family="moe", layers=61,
+                d_model=7168, heads=128, kv_heads=128, d_ff=18432,
+                vocab=129280, moe_experts=256, moe_top_k=8, moe_every=1,
+                moe_d_ff=2048, moe_shared_experts=1, dense_layers=3,
+                moe_groups=8, moe_topk_groups=4, q_lora_rank=1536,
+                kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                v_head_dim=128, mtp_layers=1, norm_eps=1e-6),
     ParallelismPlan(tp=2, pp=16, dp=8, ep=8, gpus_per_pod_per_replica=32,
-                    microbatches=128),
-    source="paper Table I [DeepSeek-V3]")
+                    microbatches=128, stage_layers=(4,) * 15 + (1,),
+                    ep_dispatch_bytes=1, ep_combine_bytes=2),
+    source="paper Table I [DeepSeek-V3, arXiv:2412.19437]")
 
 PAPER_WORKLOADS = {
     "gpt-7b": GPT_7B,

@@ -86,7 +86,7 @@ class GAOptions:
     mutation_rate: float = 0.25   # per-gene probability of a +/-1 step
     seed: int = 0
     backend: str = "auto"         # numpy | jax | auto
-    jax_task_limit: int = 1200
+    jax_task_limit: int = 4096
     time_limit: float = 120.0
     port_weight: float = 1e-9     # lexicographic secondary objective
     # engine knobs for the jax DES (kernel backend, bucketed jit cache);

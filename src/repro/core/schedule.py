@@ -17,12 +17,16 @@ Dependency categories (paper Fig. 3a):
 
 EP placement assumption: EP groups stride across DP replicas within a
 stage (Placement.ep_groups), so the all-to-all is inter-pod even when a
-replica's whole pipeline fits in one pod.  Under the single-replica
-projection (reduce_replicas=True) each EP group is represented by the pair
-0 -> 1 plus its isomorphic wraparound image 1 -> 0 -- the same
-representative-pair treatment as the DP ring, port-exact per pod but
-concentrating the (ep-1)-peer fan-out onto one pod pair.  jobs with ep == 1
-build DAGs bit-identical to the pre-MoE builder.
+replica's whole pipeline fits in one pod.  Each burst of one replica's
+stage shard fans out to every other member of its group, one directed
+task per peer carrying that peer's share (JobSpec.ep_a2a_peer_volume).
+Under the single-replica projection (reduce_replicas=True) replica 0's
+fan-out 0 -> k, k = 1 .. span-1, stands for the group: it is port-exact
+at pod 0, whose egress it is, and lifts to the whole group when
+x[0,k] = x[0,span-k], because the ingress k -> 0 is the rotational image
+of 0 -> span-k.  The full-replica builder puts the all-to-all on every
+ordered pair of each group.  Jobs with ep == 1 build DAGs bit-identical
+to the pre-MoE builder.
 
 Graph reduction replaces chains of intra-pod nodes between inter-pod tasks by
 rigid-delay edges delta (Eq. 2).  Because completion-to-start edges over a
@@ -168,53 +172,54 @@ def build_full_dag(job: JobSpec, cluster: ClusterSpec,
     # (1c) expert-parallel all-to-all (MoE dispatch + combine per stage).
     # EP groups stride across DP replicas within a stage, so the all-to-all
     # crosses pods even when a replica's whole pipeline fits in one pod.
-    # Each task aggregates one replica's full a2a egress for one
-    # (microbatch, MoE stage, direction) onto its representative ring pair;
-    # under the single-replica projection we keep the pair 0 -> 1 plus the
-    # isomorphic wraparound image 1 -> 0, exactly like the DP ring below.
-    # The fwd a2a is wired F(s) -> a2a -> F(s+1) (B(s) at the last stage)
-    # and the bwd a2a B(s) -> a2a -> B(s-1): with atomic compute nodes the
-    # intra-layer dispatch/combine collapses onto the stage boundary, where
-    # it contends with the PP transfer -- the concurrent-demand burst the
-    # traffic-matrix view obscures.
+    # Each task carries one member's egress to one peer for one
+    # (microbatch, MoE stage, direction); the projection keeps replica 0's
+    # fan-out (module docstring).  The fwd a2a is wired F(s) -> a2a ->
+    # F(s+1) (B(s) at the last stage) and the bwd a2a B(s) -> a2a ->
+    # B(s-1): with atomic compute nodes the intra-layer dispatch/combine
+    # collapses onto the stage boundary, where it contends with the PP
+    # transfer -- the concurrent-demand burst the traffic-matrix view
+    # obscures.
     ep_span = placement.ep_span
     if ep_span >= 2 and any(job.moe_stage_layers):
-        if reduce_replicas:
-            # projection: pair 0 -> 1 plus wraparound image, replica-0 gates
-            # (ep_span >= 2 implies dp >= 2, so replica 1's pods exist)
-            ep_groups = [([(0, 1), (1, 0)], [0])]
-        else:
-            # collective gating: every group member's compute node bounds
-            # every pair task of its group
-            ep_groups = [
-                ([(g * ep_span + i, g * ep_span + (i + 1) % ep_span)
-                  for i in range(ep_span)],
-                 list(range(g * ep_span, (g + 1) * ep_span)))
-                for g in range(job.dp // ep_span)]
-        for pairs, gates in ep_groups:
-            for s in range(S):
-                vol = job.ep_a2a_stage_volume(s)
-                if vol <= 0.0:
-                    continue
-                for b in range(1, MB + 1):
-                    for r_src, r_dst in pairs:
-                        pod_s = placement.pod_of(r_src, s)
-                        pod_d = placement.pod_of(r_dst, s)
-                        ca = comm_node(pod_s, pod_d, vol, job.tp,
-                                       placement.gpu_ids(r_src, s),
-                                       placement.gpu_ids(r_dst, s),
-                                       "ep_a2a_fwd", (r_src, b, s))
-                        cb = comm_node(pod_d, pod_s, vol, job.tp,
-                                       placement.gpu_ids(r_dst, s),
-                                       placement.gpu_ids(r_src, s),
-                                       "ep_a2a_bwd", (r_dst, b, s))
-                        for r in gates:
-                            g.link(fwd[(r, b, s)], ca)
-                            g.link(ca, fwd[(r, b, s + 1)] if s < S - 1
-                                   else bwd[(r, b, s)])
-                            g.link(bwd[(r, b, s)], cb)
-                            if s > 0:
-                                g.link(cb, bwd[(r, b, s - 1)])
+        with span("dag.ep_a2a") as sp:
+            if reduce_replicas:
+                # replica 0's fan-out, gated by replica 0 alone (ep_span
+                # >= 2 implies dp >= ep_span, so the peers' pods exist)
+                ep_groups = [([(0, k) for k in range(1, ep_span)], [0])]
+            else:
+                # every ordered pair of each group; collective gating:
+                # every member's compute node bounds every task
+                ep_groups = [([(i, j) for i in grp for j in grp if i != j],
+                              list(grp)) for grp in placement.ep_groups()]
+            ep_tasks, ep_bytes = 0, 0.0
+            for pairs, gates in ep_groups:
+                for s in range(S):
+                    vol = job.ep_a2a_peer_volume(s)
+                    if vol <= 0.0:
+                        continue
+                    for b in range(1, MB + 1):
+                        for r_src, r_dst in pairs:
+                            pod_s = placement.pod_of(r_src, s)
+                            pod_d = placement.pod_of(r_dst, s)
+                            src = placement.gpu_ids(r_src, s)
+                            dst = placement.gpu_ids(r_dst, s)
+                            tag = (r_src, r_dst, b, s)
+                            ca = comm_node(pod_s, pod_d, vol, job.tp, src,
+                                           dst, "ep_a2a_fwd", tag)
+                            cb = comm_node(pod_s, pod_d, vol, job.tp, src,
+                                           dst, "ep_a2a_bwd", tag)
+                            # replicas never share a pod: both inter-pod
+                            ep_tasks += 2
+                            ep_bytes += 2 * vol
+                            for r in gates:
+                                g.link(fwd[(r, b, s)], ca)
+                                g.link(ca, fwd[(r, b, s + 1)] if s < S - 1
+                                       else bwd[(r, b, s)])
+                                g.link(bwd[(r, b, s)], cb)
+                                if s > 0:
+                                    g.link(cb, bwd[(r, b, s - 1)])
+            sp.set(tasks=ep_tasks, peers=ep_span - 1, bytes=ep_bytes)
 
     # (3) gradient dependencies: DP ring sync per stage after last backward
     if job.dp >= 2:
@@ -302,7 +307,10 @@ def reduce_dag(full: FullDAG, cluster: ClusterSpec,
             lag[u] = {o: d + dur for o, d in acc.items()}
 
     if prune_dominated:
-        edges = _prune_dominated(edges, tasks, cluster)
+        with span("dag.prune") as sp:
+            edges_in = len(edges)
+            edges = _prune_dominated(edges, tasks, cluster)
+            sp.set(edges_in=edges_in, edges_kept=len(edges))
 
     deps = [Dep(pre, succ, delta) for (pre, succ), delta in sorted(edges.items())]
     return CommDAG(tasks=tasks, deps=deps, cluster=cluster, meta=meta or {})
@@ -343,7 +351,7 @@ def build_comm_dag(job: JobSpec, inter_pod_gbps: float = 400.0,
     """JobSpec -> reduced inter-pod CommDAG (the paper's (M, D) input).
 
     Traced as the `dag.build` span, with the reduced DAG's task,
-    dependency and pod counts."""
+    dependency and pod counts and its expert-parallel all-to-all tasks."""
     with span("dag.build") as sp:
         placement = job.placement(reverse_stages)
         if cluster is None:
@@ -358,5 +366,6 @@ def build_comm_dag(job: JobSpec, inter_pod_gbps: float = 400.0,
         dag = reduce_dag(full, cluster, prune_dominated=prune_dominated,
                          meta=meta)
         sp.set(tasks=dag.num_real_tasks, deps=len(dag.deps),
-               pods=cluster.num_pods)
+               pods=cluster.num_pods,
+               ep_tasks=sum(t.kind.startswith("ep_a2a") for t in dag.tasks))
     return dag

@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.topo_plan --arch deepseek-671b \
         --bandwidth 400 --methods prop-alloc,iter-halve,delta-fast \
-        --microbatches 32 --port-min --out plan.json
+        --microbatches 16 --port-min --out plan.json
 
 Prints per-method NCT / makespan / port usage and (optionally) writes the
 chosen logical topology matrix for the OCS controller.

@@ -71,7 +71,7 @@ F32 = jnp.float32
 
 
 @pytest.mark.parametrize("cons,n", [(80, 832),     # megatron-462b bucket
-                                    (72, 1088)])   # deepseek-671b bucket
+                                    (168, 3648)])  # deepseek-671b bucket
 def test_fill_round_compiles(compile_v5e, cons, n):
     compile_v5e(
         lambda w, lvl, unf: ops.fill_round(w, lvl, unf, backend="pallas",
