@@ -60,24 +60,26 @@ def reachability(dag: CommDAG, backend: str = "auto") -> np.ndarray:
 
 
 # -------------------------------------------------------------------- MWIS
-def mwis(weights: np.ndarray, adj: np.ndarray, exact_limit: int = 40
-         ) -> float:
+def mwis(weights: np.ndarray, adj: np.ndarray, exact_limit: int = 40,
+         floor: float = 0.0) -> float:
     """Maximum-weight independent set (exact branch & bound with greedy
     fallback above `exact_limit` vertices).
 
     weights: (k,) positive vertex weights; adj: (k, k) boolean symmetric.
+    floor: a weight the caller already holds; the exact search prunes every
+    branch that cannot beat it.  Returns max(floor, the weight found).
     """
     k = len(weights)
     if k == 0:
-        return 0.0
+        return float(floor)
     if not adj.any():
-        return float(weights.sum())
+        return max(float(floor), float(weights.sum()))
     if k > exact_limit:
-        return _mwis_greedy(weights, adj)
+        return max(float(floor), _mwis_greedy(weights, adj))
     order = np.argsort(-weights)
     w = weights[order].astype(float)
     a = adj[np.ix_(order, order)]
-    best = 0.0
+    best = float(floor)
 
     def rec(idx: int, avail: np.ndarray, acc: float) -> None:
         nonlocal best
@@ -135,23 +137,32 @@ def x_upper_bound(dag: CommDAG, t_up: float | None = None,
     reach = reachability(dag, closure_backend)
     excl = reach | reach.T  # mutual exclusivity: dependency-linked pairs
 
+    U = np.asarray(dag.cluster.port_limits)
+    all_flows = dag.flows()
     for (u, v), tids in dag.tasks_on_pair().items():
         tids = np.asarray(tids)
+        cap = min(U[u], U[v])
         bounds = np.unique(np.concatenate([est[tids], lct[tids]]))
-        flows = dag.flows()[tids]
-        for lo, hi in zip(bounds[:-1], bounds[1:]):
-            mid = 0.5 * (lo + hi)
-            sel = (est[tids] <= mid) & (mid < lct[tids])
-            if not sel.any():
-                continue
-            a_tids = tids[sel]
+        mids = 0.5 * (bounds[:-1] + bounds[1:])
+        # (interval, task) co-window masks, heaviest interval first: the
+        # pair's final bound is min(max(x_uv, x_vu), cap), so an interval
+        # whose total flow weight cannot raise it is never solved, and once
+        # it reaches the port cap no interval can move it.
+        sel = (est[tids] <= mids[:, None]) & (mids[:, None] < lct[tids])
+        flows = all_flows[tids]
+        load = sel @ flows
+        for i in np.argsort(-load, kind="stable"):
+            held = max(xbar[u, v], xbar[v, u])
+            if held >= cap or np.ceil(load[i]) <= held:
+                break
+            a_tids = tids[sel[i]]
             sub = excl[np.ix_(a_tids, a_tids)]
-            cmax = mwis(flows[sel], sub, exact_limit=exact_limit)
+            cmax = mwis(flows[sel[i]], sub, exact_limit=exact_limit,
+                        floor=held)
             xbar[u, v] = max(xbar[u, v], int(np.ceil(cmax)))
     # bidirectional circuits (Eq. 6): bound the symmetric pair jointly
     xbar = np.maximum(xbar, xbar.T)
     # never below 1 for active pairs (connectivity), never above ports
-    U = np.asarray(dag.cluster.port_limits)
     for i, j in dag.undirected_pairs():
         cap = min(U[i], U[j])
         xbar[i, j] = xbar[j, i] = max(1, min(xbar[i, j], cap))

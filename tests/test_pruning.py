@@ -83,6 +83,100 @@ def test_mwis_exact_small():
     assert mwis(w, np.zeros((3, 3), bool)) == pytest.approx(7.0)
 
 
+def _mwis_brute(w, adj):
+    k = len(w)
+    best = 0.0
+    for mask in range(1 << k):
+        chosen = [i for i in range(k) if mask >> i & 1]
+        if not adj[np.ix_(chosen, chosen)].any():
+            best = max(best, float(w[chosen].sum()))
+    return best
+
+
+def test_mwis_floor_is_max_of_floor_and_exact_weight():
+    rng = np.random.default_rng(7)
+    for _ in range(40):
+        k = int(rng.integers(1, 9))
+        w = rng.integers(1, 5, k).astype(float)
+        upper = np.triu(rng.random((k, k)) < 0.4, 1)
+        adj = upper | upper.T
+        exact = _mwis_brute(w, adj)
+        assert mwis(w, adj) == exact
+        for floor in (0.0, exact - 1, exact, exact + 2):
+            assert mwis(w, adj, floor=max(floor, 0.0)) == max(floor, exact)
+
+
+def _xbound_every_interval(dag, exact_limit):
+    """Alg. 2 as written: solve every co-window interval of every pair."""
+    from repro.core.xbound import reachability
+    P = dag.cluster.num_pods
+    xbar = np.zeros((P, P), dtype=np.int64)
+    est, lct = cal_task_time_windows(dag, estimate_t_up(DESProblem(dag)))
+    reach = reachability(dag)
+    excl = reach | reach.T
+    for (u, v), tids in dag.tasks_on_pair().items():
+        tids = np.asarray(tids)
+        bounds = np.unique(np.concatenate([est[tids], lct[tids]]))
+        flows = dag.flows()[tids]
+        for lo, hi in zip(bounds[:-1], bounds[1:]):
+            mid = 0.5 * (lo + hi)
+            sel = (est[tids] <= mid) & (mid < lct[tids])
+            if sel.any():
+                sub = excl[np.ix_(tids[sel], tids[sel])]
+                cmax = mwis(flows[sel], sub, exact_limit=exact_limit)
+                xbar[u, v] = max(xbar[u, v], int(np.ceil(cmax)))
+    xbar = np.maximum(xbar, xbar.T)
+    U = np.asarray(dag.cluster.port_limits)
+    for i, j in dag.undirected_pairs():
+        xbar[i, j] = xbar[j, i] = max(1, min(xbar[i, j], min(U[i], U[j])))
+    return xbar
+
+
+def _sparse_dag(rng):
+    """Tasks on a few pods with few dependencies, so many co-windowed
+    tasks are independent and the port cap is often reached."""
+    pods = int(rng.integers(2, 4))
+    n = int(rng.integers(4, 25))
+    tasks = [make_virtual()]
+    for tid in range(1, n + 1):
+        src = int(rng.integers(0, pods))
+        dst = int((src + 1 + rng.integers(0, pods - 1)) % pods)
+        f = int(rng.integers(1, 4))
+        g = tuple(range(10 * tid, 10 * tid + f))
+        tasks.append(CommTask(tid, src, dst, f,
+                              float(rng.uniform(0.5, 4.0) * 1e9), g,
+                              tuple(x + 5000 for x in g), kind="rand"))
+    deps = [Dep(0, tid, float(rng.uniform(0, 0.05)))
+            for tid in range(1, n + 1)]
+    for tid in range(2, n + 1):
+        if rng.random() < 0.2:
+            deps.append(Dep(int(rng.integers(1, tid)), tid,
+                            float(rng.uniform(0, 0.05))))
+    ports = tuple(int(rng.integers(2, 16)) for _ in range(pods))
+    return CommDAG(tasks=tasks, deps=deps, cluster=ClusterSpec(
+        num_pods=pods, port_limits=ports, nic_bandwidth=50e9))
+
+
+@pytest.mark.parametrize("exact_limit", [40, 2])
+def test_xbound_equals_every_interval_scan(exact_limit):
+    """Solving the heaviest intervals first and stopping at the port cap
+    gives the bound that solving every interval gives, on the exact and
+    on the greedy path."""
+    rng = np.random.default_rng(11)
+    for _ in range(60):
+        d = _sparse_dag(rng)
+        assert (x_upper_bound(d, exact_limit=exact_limit)
+                == _xbound_every_interval(d, exact_limit)).all()
+
+
+@pytest.mark.parametrize("exact_limit", [40, 2])
+@settings(max_examples=25, deadline=None)
+@given(random_comm_dags(max_pods=3, max_tasks=16))
+def test_property_xbound_equals_every_interval_scan(exact_limit, dag):
+    assert (x_upper_bound(dag, exact_limit=exact_limit)
+            == _xbound_every_interval(dag, exact_limit)).all()
+
+
 def test_xbound_upper_bounds_des_concurrency(dag):
     """Alg. 2's bound must dominate any simultaneous flow weight the DES
     actually achieves on an abundant topology."""
