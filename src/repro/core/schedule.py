@@ -41,12 +41,19 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 from dataclasses import dataclass, field
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.core.cluster import ClusterSpec, Placement
 from repro.core.dag import VIRTUAL, CommDAG, CommTask, Dep, make_virtual
 from repro.core.traffic import JobSpec
+from repro.kernels import ops
+from repro.kernels.maxplus import TILE
 from repro.obs.tracing import span
 
 
@@ -309,8 +316,8 @@ def reduce_dag(full: FullDAG, cluster: ClusterSpec,
     if prune_dominated:
         with span("dag.prune") as sp:
             edges_in = len(edges)
-            edges = _prune_dominated(edges, tasks, cluster)
-            sp.set(edges_in=edges_in, edges_kept=len(edges))
+            edges, how = _prune_dominated(edges, tasks, cluster)
+            sp.set(edges_in=edges_in, edges_kept=len(edges), **how)
 
     deps = [Dep(pre, succ, delta) for (pre, succ), delta in sorted(edges.items())]
     return CommDAG(tasks=tasks, deps=deps, cluster=cluster, meta=meta or {})
@@ -318,12 +325,35 @@ def reduce_dag(full: FullDAG, cluster: ClusterSpec,
 
 def _prune_dominated(edges: dict[tuple[int, int], float],
                      tasks: list[CommTask], cluster: ClusterSpec,
-                     eps: float = 1e-12) -> dict[tuple[int, int], float]:
-    """Drop (o, n, delta) if some 2-path o -> m -> n already enforces it."""
-    tau_min = [0.0] * len(tasks)
+                     eps: float = 1e-12
+                     ) -> tuple[dict[tuple[int, int], float], dict]:
+    """Drop (o, n, delta) if some 2-path o -> m -> n already enforces it:
+    delta1 + tau_min[m] + delta2 >= delta - eps, in float64.
+
+    On a TPU whose free memory holds the dense (n_pad, n_pad) matrices,
+    one max-plus product decides the edges on the device and the host
+    decides again, by the same predicate, the few that float32 leaves too
+    close to their bound; elsewhere a loop over each edge's 2-paths decides
+    them.  Both keep the same edges.  Returns the kept edges and the
+    `dag.prune` span's attributes: the path, the edges rechecked on the
+    host and n_pad."""
+    tau_min = np.zeros(len(tasks))
     for t in tasks:
         if not t.is_virtual:
             tau_min[t.tid] = t.volume / (t.flows * cluster.nic_bandwidth)
+    n_pad = -(-len(tasks) // TILE) * TILE
+    if edges and _prune_fits_device(n_pad):
+        kept, rechecked = _prune_device(edges, tau_min, n_pad, eps)
+        path = "device"
+    else:
+        kept = _prune_host(edges, tau_min.tolist(), eps)
+        rechecked, path = 0, "host"
+    return kept, {"path": path, "rechecked": rechecked, "n_pad": n_pad}
+
+
+def _prune_host(edges: dict[tuple[int, int], float], tau_min: list[float],
+                eps: float) -> dict[tuple[int, int], float]:
+    """The kept edges, each tested against its origin's 2-paths in turn."""
     out_of: dict[int, list[tuple[int, float]]] = collections.defaultdict(list)
     for (o, m), d in edges.items():
         out_of[o].append((m, d))
@@ -340,6 +370,105 @@ def _prune_dominated(edges: dict[tuple[int, int], float],
         if not dominated:
             kept[(o, nn)] = delta
     return kept
+
+
+# The device prune's codes of an edge.
+_KEPT, _DOMINATED, _UNDECIDED = 0, 1, 2
+# The matrices' side n_pad is padded to the max-plus kernel's tile, so the
+# kernel pads nothing and each job compiles once.
+# Device bytes per entry of the (n_pad, n_pad) matrices: three float32
+# buffers live at once (D, the kernel's transposed copy of D + tau_min, the
+# product C) with the int8 codes, and one byte more covers tau_min and
+# alignment (tests/test_tpu_compile.py).
+_PRUNE_DEVICE_BYTES = 3 * 4 + 1 + 1
+
+
+def _prune_fits_device(n_pad: int) -> bool:
+    """Whether the device path runs: on a TPU (the rule by which `ops`
+    picks the Pallas kernels) whose free memory holds its buffers."""
+    if jax.default_backend() != "tpu":
+        return False
+    stats = jax.devices()[0].memory_stats() or {}
+    free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+    return _PRUNE_DEVICE_BYTES * n_pad * n_pad <= free
+
+
+def _f32_band(delta: np.ndarray, tau_min: np.ndarray, eps: float) -> float:
+    """How far the float32 test may stand from the float64 one.
+
+    With u = 2**-24 and S = 2 max|delta| + max tau_min, which bounds every
+    operand and partial sum, the float32 sum (d1 + tau) + d2 differs from
+    the exact one by at most u S for converting its three operands and
+    u S for each of its two additions; delta's conversion and forming
+    delta -/+ band add u S / 2 each, and the float64 predicate's own
+    roundings about 2**-52 S.  That is under 4.01 u S with rounding to
+    nearest; 8 u S also holds where hardware rounds only faithfully (an
+    error of up to one ulp an operation).  Beyond the band, the float32
+    comparison decides as the float64 one with its eps does."""
+    s = 2.0 * float(np.abs(delta).max()) + float(tau_min.max())
+    return 8.0 * 2.0**-24 * s + eps
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
+def _dominance_codes(d, tau, band, *, backend=None, interpret=None):
+    """Per entry of the lag matrix d (NEG_INF where no edge): _DOMINATED
+    where the best 2-path C = max_m (d[o, m] + tau[m]) + d[m, n] reaches
+    d + band, _KEPT where it stays below d - band, else _UNDECIDED.  The
+    diagonal is NEG_INF, so m == o and m == n take no part."""
+    c = ops.maxplus(d + tau[None, :], d, backend=backend, interpret=interpret)
+    return jnp.where(c >= d + band, _DOMINATED,
+                     jnp.where(c < d - band, _KEPT, _UNDECIDED)
+                     ).astype(jnp.int8)
+
+
+def _prune_device(edges: dict[tuple[int, int], float], tau_min: np.ndarray,
+                  n_pad: int, eps: float, *, backend: str | None = None,
+                  interpret: bool | None = None
+                  ) -> tuple[dict[tuple[int, int], float], int]:
+    """The kept edges, decided on the device, and how many of them the
+    host decided again in float64."""
+    e = len(edges)
+    keys = np.fromiter(itertools.chain.from_iterable(edges), np.int32,
+                       2 * e).reshape(e, 2)
+    delta = np.fromiter(edges.values(), np.float64, e)
+    src, dst = keys[:, 0], keys[:, 1]
+    d = np.full((n_pad, n_pad), ops.NEG_INF, np.float32)
+    d[src, dst] = delta
+    tau = np.zeros(n_pad, np.float32)
+    tau[:len(tau_min)] = tau_min
+    band = np.float32(_f32_band(delta, tau_min, eps))
+    codes = np.asarray(_dominance_codes(d, tau, band, backend=backend,
+                                        interpret=interpret))[src, dst]
+    near = np.flatnonzero(codes == _UNDECIDED)
+    if len(near):
+        codes[near] = np.where(_dominated_f64(keys, delta, tau_min, eps, near),
+                               _DOMINATED, _KEPT)
+    keep = codes == _KEPT
+    kept = dict(zip(map(tuple, keys[keep].tolist()), delta[keep].tolist()))
+    return kept, len(near)
+
+
+def _dominated_f64(keys: np.ndarray, delta: np.ndarray, tau_min: np.ndarray,
+                   eps: float, rows: np.ndarray) -> np.ndarray:
+    """The loop's float64 predicate for the edges `rows`, each vectorised
+    over the out-edges of its origin, summed in the loop's order."""
+    n = len(tau_min)
+    flat = keys[:, 0].astype(np.int64) * n + keys[:, 1]
+    by_key = np.argsort(flat)
+    flat_sorted = flat[by_key]
+    by_src = np.argsort(keys[:, 0], kind="stable")
+    start = np.searchsorted(keys[by_src, 0], np.arange(n + 1))
+    out = np.zeros(len(rows), bool)
+    for i, r in enumerate(rows):
+        o, nn = keys[r]
+        edge = by_src[start[o]:start[o + 1]]
+        m = keys[edge, 1]
+        want = m.astype(np.int64) * n + nn
+        at = np.minimum(np.searchsorted(flat_sorted, want), len(flat) - 1)
+        two_path = delta[edge] + tau_min[m] + delta[by_key[at]]
+        out[i] = bool(np.any((flat_sorted[at] == want)
+                             & (two_path >= delta[r] - eps)))
+    return out
 
 
 # ------------------------------------------------------------------- facade

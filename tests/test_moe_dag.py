@@ -266,6 +266,10 @@ def test_ep_spans_record_the_fan_out_and_the_prune():
         sum(t.volume for t in ep_tasks(dag)))
     assert recs["dag.prune"]["edges_kept"] == len(dag.deps) \
         <= recs["dag.prune"]["edges_in"]
+    # the CPU keeps the loop; a TPU decides on the device (test_prune_device)
+    assert recs["dag.prune"]["path"] == "host"
+    assert recs["dag.prune"]["rechecked"] == 0
+    assert recs["dag.prune"]["n_pad"] == 512  # 256 tasks and the source
 
 
 # ------------------------------------------------------------ DeepSeek-V3

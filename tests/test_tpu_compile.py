@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import PAPER_WORKLOADS, make_job
+from repro.core import schedule
 from repro.core.des import DESProblem
 from repro.core.des_jax import (_ARRAY_FIELDS, CompiledDES, DESArrays,
                                 DESOptions, PadSpec, _StaticCfg,
@@ -89,6 +90,20 @@ def test_maxplus_compiles(compile_v5e):
     compile_v5e(lambda a, b: ops.maxplus(a, b, backend="pallas",
                                          interpret=False),
                 ((300, 300), F32), ((300, 300), F32))
+
+
+def test_dominance_prune_compiles(compile_v5e):
+    """The DAG build's dominance prune (max-plus kernel and edge codes) at
+    deepseek-671b's 3617 tasks padded to the kernel's tile, within the
+    device bytes by which the build decides that it fits."""
+    n = 3840
+    m = compile_v5e(
+        lambda d, tau, band: schedule._dominance_codes(
+            d, tau, band, backend="pallas", interpret=False),
+        ((n, n), F32), ((n,), F32), ((), F32)).memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+    assert used <= schedule._PRUNE_DEVICE_BYTES * n * n
 
 
 def test_fused_batch_genomes_step_compiles(compile_v5e):
