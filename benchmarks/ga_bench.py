@@ -1,10 +1,6 @@
 """GA engine throughput: the population-array-resident DELTA-Fast hot loop
-vs the legacy per-genome implementation, at identical seed and generation
-budget, plus batched vs serial `trim_ports`.
-
-Emits the measured speedup and the relative makespan delta (the acceptance
-bar: >= 3x wall clock at unchanged-or-better makespan on the medium
-workload, identical trim_ports port count and makespan).
+at a fixed seed and generation budget, plus `trim_ports` on its cost-gated
+`auto` path and its forced batched path (the two must agree).
 """
 from __future__ import annotations
 
@@ -14,7 +10,6 @@ import time
 import numpy as np
 
 from benchmarks.common import Row, bench_dag, save_json
-from repro.core import _ga_legacy as legacy
 from repro.core.des import DESProblem, simulate
 from repro.core.ga import GAOptions, TopologySpace, delta_fast, trim_ports
 
@@ -40,21 +35,9 @@ def run(full: bool = False) -> list[Row]:
                     f"seconds={t_new:.2f};gens={new.generations};"
                     f"evals={new.evaluations};makespan={new.makespan:.6f}"))
 
-    t0 = time.time()
-    old = legacy.delta_fast(dag, legacy.GAOptions(**_opts(gens)))
-    t_old = time.time() - t0
-    rows.append(Row(f"ga/legacy/{WORKLOAD}/mb{mb}", t_old * 1e6,
-                    f"seconds={t_old:.2f};gens={old.generations};"
-                    f"evals={old.evaluations};makespan={old.makespan:.6f}"))
-
-    speedup = t_old / max(t_new, 1e-9)
-    rel = (new.makespan - old.makespan) / max(old.makespan, 1e-12)
-    rows.append(Row(f"ga/speedup/{WORKLOAD}/mb{mb}", t_new * 1e6,
-                    f"speedup={speedup:.2f}x;rel_makespan={rel:+.2e}"))
-
-    # trim_ports: batched candidate rounds vs serial one-drop-at-a-time,
-    # identical result required.  Trim a port-saturated feasible topology
-    # (X̄ pushed through Alg. 6 repair) so the sweep has real work to do.
+    # trim_ports: the cost-gated auto path vs the forced batched path.
+    # Trim a port-saturated feasible topology (X̄ pushed through Alg. 6
+    # repair) so the sweep has real work to do.
     problem = DESProblem(dag)
     space = TopologySpace(dag)
     g_fat, _ = space.repair(space.xbar.copy(), np.random.default_rng(0))
@@ -65,24 +48,16 @@ def run(full: bool = False) -> list[Row]:
     t0 = time.time()
     xt_jax = trim_ports(dag, x_fat, backend="jax")   # forced batched path
     t_tjax = time.time() - t0
-    t0 = time.time()
-    xt_old = legacy.trim_ports(dag, x_fat)
-    t_told = time.time() - t0
-    same = bool((xt_new == xt_old).all()) and bool((xt_jax == xt_old).all())
     ms_new = simulate(problem, xt_new).makespan
-    ms_old = simulate(problem, xt_old).makespan
+    ms_jax = simulate(problem, xt_jax).makespan
     rows.append(Row(
         f"ga/trim_ports/{WORKLOAD}/mb{mb}", t_tnew * 1e6,
         f"seconds={t_tnew:.2f};jax_seconds={t_tjax:.2f};"
-        f"legacy_seconds={t_told:.2f};identical={same};"
-        f"ports={int(xt_new.sum())};legacy_ports={int(xt_old.sum())};"
-        f"rel_makespan={(ms_new - ms_old) / max(ms_old, 1e-12):+.2e}"))
+        f"ports={int(xt_new.sum())};jax_ports={int(xt_jax.sum())};"
+        f"rel_makespan={(ms_jax - ms_new) / max(ms_new, 1e-12):+.2e}"))
 
     save_json("ga_bench", {
         "workload": WORKLOAD, "mb": mb, "generations": gens,
-        "vectorized_seconds": t_new, "legacy_seconds": t_old,
-        "speedup": speedup, "vectorized_makespan": new.makespan,
-        "legacy_makespan": old.makespan,
-        "trim_identical": same, "trim_auto_seconds": t_tnew,
-        "trim_jax_seconds": t_tjax, "trim_legacy_seconds": t_told})
+        "vectorized_seconds": t_new, "vectorized_makespan": new.makespan,
+        "trim_auto_seconds": t_tnew, "trim_jax_seconds": t_tjax})
     return rows

@@ -188,9 +188,12 @@ class TopologySpace:
         return np.asarray(x)[self.edge_u, self.edge_v].astype(np.int64)
 
     def to_matrix_batch(self, genomes: np.ndarray) -> np.ndarray:
-        """(S, E) genomes -> (S, P, P) symmetric topologies in one scatter."""
-        G = np.asarray(genomes, dtype=np.int64).reshape(-1, self.E)
-        X = np.zeros((len(G), self.P, self.P), dtype=np.int64)
+        """(S, E) genomes -> (S, P, P) symmetric topologies in one scatter:
+        integer circuit counts, or float for the fractional fabric states
+        of a plane stack (`plane_state_genomes`)."""
+        G = np.atleast_2d(genomes)
+        X = np.zeros((len(G), self.P, self.P),
+                     dtype=np.result_type(G, np.int64))
         X[:, self.edge_u, self.edge_v] = G
         X[:, self.edge_v, self.edge_u] = G
         return X
@@ -280,7 +283,9 @@ class _CachedFitness:
     batch compiles once and every generation does O(1) host<->device
     transfers), and the lexicographic port penalty.  Subclasses provide
     `_raw_scores` mapping unique (S, E) genomes to makespan-like scores
-    (lower is better, INF marks infeasible)."""
+    (lower is better, INF marks infeasible), and the exact (numpy DES)
+    evaluation `exact` of one genome with its scalar `exact_score`, which
+    `_rerank` certifies the winner with."""
 
     def __init__(self, space: TopologySpace, opts: GAOptions, n_tasks: int):
         self.space = space
@@ -303,6 +308,12 @@ class _CachedFitness:
         return genomes, k
 
     def _raw_scores(self, genomes: np.ndarray) -> np.ndarray:
+        raise NotImplementedError
+
+    def exact(self, genome: np.ndarray):
+        raise NotImplementedError
+
+    def exact_score(self, raw) -> float:
         raise NotImplementedError
 
     def __call__(self, population: np.ndarray) -> np.ndarray:
@@ -339,7 +350,7 @@ class BatchedFitness(_CachedFitness):
             from repro.core.des_jax import JaxDES
             self._jd = JaxDES(self.problem, options=opts.des_options)
 
-    def _raw_makespans(self, genomes: np.ndarray) -> np.ndarray:
+    def _raw_scores(self, genomes: np.ndarray) -> np.ndarray:
         """Makespan (INF if infeasible) for each unique genome row."""
         if self._jd is not None:
             genomes, k = self._padded(genomes)
@@ -347,14 +358,14 @@ class BatchedFitness(_CachedFitness):
                 genomes, self.space.edge_u, self.space.edge_v)
             self.batch_calls += 1
             return np.where(feas, ms, INF)[:k]
-        return np.array([simulate(self.problem, x).makespan
-                         for x in self.space.to_matrix_batch(genomes)])
+        return np.array([self.exact(g) for g in genomes])
 
-    _raw_scores = _raw_makespans
+    def exact(self, genome: np.ndarray) -> float:
+        """Exact (numpy DES) makespan of one genome."""
+        return simulate(self.problem, self.space.to_matrix(genome)).makespan
 
-
-# backwards-compatible alias (pre-vectorization name)
-_Fitness = BatchedFitness
+    def exact_score(self, raw: float) -> float:
+        return float(raw)
 
 
 def _tournament_batch(fitness: np.ndarray, rng: np.random.Generator,
@@ -387,9 +398,9 @@ def _evolve(space: TopologySpace, fit, opts: GAOptions,
             ) -> tuple[np.ndarray, float, list[float], int]:
     """The shared GA driver (Alg. 3 body): init + repair + generational
     loop, fitness-agnostic.  `fit` maps a (S, E) population to (S,) scores
-    (lower is better); both `delta_fast` and `delta_robust` route through
-    this exact loop, so a singleton ensemble consumes the RNG identically
-    to the single-DAG path.  Returns (best_g, best_f, history, gen)."""
+    (lower is better); every DELTA engine routes through this exact loop,
+    so a singleton ensemble consumes the RNG identically to the single-DAG
+    path.  Returns (best_g, best_f, history, gen)."""
     pop = space.random_init_batch(rng, opts.pop_size)
     # seed candidates (e.g. baselines) -- repaired into the population
     for s in (seeds or []):
@@ -427,6 +438,53 @@ def _evolve(space: TopologySpace, fit, opts: GAOptions,
     return best_g, best_f, history, gen
 
 
+def _rerank(fit: _CachedFitness, best_g: np.ndarray, top: int):
+    """Exact re-rank of the `top` best distinct genomes in the fitness
+    cache (the batched jax fitness may run in float32; ~1e-5 ranking
+    noise): each finite one is scored with `fit.exact` plus the port
+    penalty, and the winner -- `best_g` if none is finite -- is evaluated
+    once more.  Returns (winner genome, its `fit.exact` evaluation)."""
+    ranked = sorted(fit.cache.items(), key=lambda kv: kv[1])[:top]
+    best_key, best_score = best_g.tobytes(), INF
+    with span("ga.rerank") as sp:
+        candidates = 0
+        for key, fval in ranked:
+            if not np.isfinite(fval):
+                continue
+            candidates += 1
+            g = np.frombuffer(key, dtype=np.int64)
+            score = fit.exact_score(fit.exact(g))
+            if np.isfinite(score):
+                score += fit.opts.port_weight * float(g.sum())
+            if score < best_score:
+                best_score, best_key = score, key
+        sp.set(candidates=candidates)
+        best_g = np.frombuffer(best_key, dtype=np.int64)
+        return best_g, fit.exact(best_g)
+
+
+def _search(fit: _CachedFitness, rng: np.random.Generator,
+            seeds: list[np.ndarray] | None, kind: str, top: int = 8,
+            **attrs):
+    """Alg. 3 end to end, as every DELTA engine runs it: `_evolve` under
+    the fitness' own wall-clock budget, then `_rerank`.  An empty pair
+    space (no inter-pod traffic) skips the search: the empty topology is
+    the one evaluation.  Returns (genome, its `fit.exact` evaluation,
+    generations, history)."""
+    space, opts = fit.space, fit.opts
+    t0 = time.time()
+    if space.E == 0:
+        g = np.zeros(0, dtype=np.int64)
+        raw = fit.exact(g)
+        fit.evaluations += 1
+        return g, raw, 0, [fit.exact_score(raw)]
+    with span("ga.evolve", kind=kind, pop=opts.pop_size, edges=space.E,
+              **attrs):
+        best_g, _, history, gen = _evolve(space, fit, opts, rng, t0, seeds)
+    g, raw = _rerank(fit, best_g, top)
+    return g, raw, gen, history
+
+
 def delta_fast(dag: CommDAG, opts: GAOptions | None = None,
                xbar: np.ndarray | None = None,
                seeds: list[np.ndarray] | None = None) -> GAResult:
@@ -436,39 +494,11 @@ def delta_fast(dag: CommDAG, opts: GAOptions | None = None,
     space = TopologySpace(dag, xbar)
     fit = BatchedFitness(dag, space, opts)
     t0 = time.time()
-
-    if space.E == 0:    # no inter-pod traffic: the empty topology is optimal
-        x = np.zeros((space.P, space.P), dtype=np.int64)
-        ms = simulate(fit.problem, x).makespan
-        return GAResult(x=x, makespan=float(ms), generations=0,
-                        evaluations=1, elapsed=time.time() - t0,
-                        history=[float(ms)], feasible=np.isfinite(ms))
-
-    with span("ga.evolve", kind="delta_fast", pop=opts.pop_size,
-              edges=space.E):
-        best_g, _, history, gen = _evolve(space, fit, opts, rng, t0, seeds)
-
-    # re-rank the best distinct candidates with the exact numpy DES (the
-    # batched jax fitness may run in float32; ~1e-5 ranking noise)
-    ranked = sorted(fit.cache.items(), key=lambda kv: kv[1])[:8]
-    best_x, best_ms = space.to_matrix(best_g), INF
-    with span("ga.rerank") as sp:
-        candidates = 0
-        for key, fval in ranked:
-            if not np.isfinite(fval):
-                continue
-            candidates += 1
-            g = np.frombuffer(key, dtype=np.int64)
-            x = space.to_matrix(g)
-            ms = simulate(fit.problem, x).makespan
-            port_pen = opts.port_weight * float(g.sum())
-            if ms + port_pen < best_ms:
-                best_ms, best_x = ms + port_pen, x
-        ms = simulate(fit.problem, best_x).makespan
-        sp.set(candidates=candidates)
-    return GAResult(x=best_x, makespan=float(ms), generations=gen,
-                    evaluations=fit.evaluations, elapsed=time.time() - t0,
-                    history=history, feasible=np.isfinite(ms))
+    g, ms, gen, history = _search(fit, rng, seeds, "delta_fast")
+    return GAResult(x=space.to_matrix(g), makespan=float(ms),
+                    generations=gen, evaluations=fit.evaluations,
+                    elapsed=time.time() - t0, history=history,
+                    feasible=np.isfinite(ms))
 
 
 # ------------------------------------------------------------- DELTA-Robust
@@ -491,17 +521,22 @@ class EnsembleFitness(_CachedFitness):
       weighted   : sum_m w_m * makespan_m
       max-regret : max_m  makespan_m / refs_m
 
-    Any member-infeasible genome scores INF.
+    Any member-infeasible genome scores INF.  `masks`, one (P, P)
+    capacity factor per member, degrades each member's fabric (the
+    k-failure objective stacks one DAG once per failure scenario).
     """
 
     def __init__(self, ensemble: DagEnsemble, space: TopologySpace,
-                 opts: GAOptions, objective: str, refs: np.ndarray):
+                 opts: GAOptions, objective: str, refs: np.ndarray,
+                 masks: Sequence[np.ndarray] | None = None):
         self.ensemble = ensemble
         self.problems = [DESProblem(m) for m in ensemble.members]
         super().__init__(space, opts, max(p.n for p in self.problems))
         self.objective = objective
         self.refs = np.asarray(refs, dtype=np.float64)
         self.weights = np.asarray(ensemble.weights, dtype=np.float64)
+        self.masks = None if masks is None else np.stack(
+            [np.asarray(m, dtype=np.float64) for m in masks])
         self._jd = None
         if self._use_jax and space.E > 0:
             from repro.core.des_jax import EnsembleJaxDES
@@ -521,23 +556,29 @@ class EnsembleFitness(_CachedFitness):
 
     def member_makespans(self, genomes: np.ndarray) -> np.ndarray:
         """(S, E) genomes -> (S, M) makespans (INF where infeasible)."""
-        genomes = np.asarray(genomes, dtype=np.int64).reshape(-1,
-                                                              self.space.E)
+        genomes = np.asarray(genomes).reshape(-1, self.space.E)
         if self._jd is not None:
             genomes, k = self._padded(genomes)
             ms, feas = self._jd.ensemble_genome_makespan(
-                genomes, self.space.edge_u, self.space.edge_v)
+                genomes, self.space.edge_u, self.space.edge_v,
+                masks=self.masks)
             self.batch_calls += 1
             return np.where(feas, ms, INF)[:k]
-        out = np.empty((len(genomes), len(self.problems)))
-        for s, x in enumerate(self.space.to_matrix_batch(genomes)):
-            out[s] = [simulate(p, x).makespan for p in self.problems]
-        return out
+        return np.array([self.exact_member_makespans(g) for g in genomes])
 
     def exact_member_makespans(self, genome: np.ndarray) -> np.ndarray:
         """Exact (numpy DES) per-member makespans of one genome."""
         x = self.space.to_matrix(genome)
-        return np.array([simulate(p, x).makespan for p in self.problems])
+        masks = [1] * len(self.problems) if self.masks is None \
+            else self.masks
+        return np.array([simulate(p, x * m).makespan
+                         for p, m in zip(self.problems, masks)])
+
+    def exact(self, genome: np.ndarray) -> np.ndarray:
+        return self.exact_member_makespans(genome)
+
+    def exact_score(self, raw: np.ndarray) -> float:
+        return float(self.scalarize(raw).max())
 
     def _raw_scores(self, genomes: np.ndarray) -> np.ndarray:
         return self.scalarize(self.member_makespans(genomes))
@@ -573,6 +614,22 @@ class RobustGAResult:
         return int(self.x.sum())
 
 
+def _ensemble_search(fit: EnsembleFitness, seeds: list[np.ndarray] | None,
+                     objective: str, kind: str,
+                     t_start: float) -> RobustGAResult:
+    """`_search` over an ensemble fitness, as `RobustGAResult`."""
+    rng = np.random.default_rng(fit.opts.seed)
+    g, ms, gen, history = _search(fit, rng, seeds, kind,
+                                  members=len(fit.problems))
+    return RobustGAResult(
+        x=fit.space.to_matrix(g), makespans=ms, regrets=ms / fit.refs,
+        refs=fit.refs, weights=np.asarray(fit.ensemble.weights),
+        objective=objective, objective_value=fit.exact_score(ms),
+        generations=gen, evaluations=fit.evaluations,
+        elapsed=time.time() - t_start, history=history,
+        feasible=bool(np.isfinite(ms).all()))
+
+
 def delta_robust(ensemble: DagEnsemble, opts: GAOptions | None = None,
                  objective: str = "max-regret",
                  refs: np.ndarray | None = None,
@@ -588,7 +645,8 @@ def delta_robust(ensemble: DagEnsemble, opts: GAOptions | None = None,
 
     `refs` are the per-member reference makespans defining regret
     (member's best single-DAG plan).  When omitted they are computed here
-    by running `delta_fast` per member with the same options.
+    by running `delta_fast` per member with the same options; the robust
+    GA still gets its own full time budget.
 
     `port_limits` overrides the cluster's per-pod budgets: the k-plane
     decomposition (`delta_planes`) searches the base topology inside the
@@ -607,54 +665,10 @@ def delta_robust(ensemble: DagEnsemble, opts: GAOptions | None = None,
         raise ValueError("refs must have one entry per ensemble member")
     if not (np.isfinite(refs) & (refs > 0)).all():
         raise ValueError(f"refs must be finite positive makespans: {refs}")
-
-    rng = np.random.default_rng(opts.seed)
     space = TopologySpace.for_ensemble(ensemble, xbar,
                                        port_limits=port_limits)
     fit = EnsembleFitness(ensemble, space, opts, objective, refs)
-    # the robust GA gets its own full time budget: the per-member ref
-    # runs above must not eat into _evolve's wall-clock limit
-    t0 = time.time()
-
-    if space.E == 0:    # no member has inter-pod traffic
-        x = np.zeros((space.P, space.P), dtype=np.int64)
-        ms = fit.exact_member_makespans(np.zeros(0, dtype=np.int64))
-        obj = float(fit.scalarize(ms[None])[0])
-        return RobustGAResult(
-            x=x, makespans=ms, regrets=ms / refs, refs=refs,
-            weights=np.asarray(ensemble.weights),
-            objective=objective, objective_value=obj, generations=0,
-            evaluations=1, elapsed=time.time() - t_start, history=[obj],
-            feasible=bool(np.isfinite(ms).all()))
-
-    with span("ga.evolve", kind="delta_robust", pop=opts.pop_size,
-              edges=space.E, members=ensemble.num_members):
-        best_g, _, history, gen = _evolve(space, fit, opts, rng, t0, seeds)
-
-    # re-rank the top distinct candidates with the exact numpy DES per
-    # member (same float32-noise guard as delta_fast)
-    ranked = sorted(fit.cache.items(), key=lambda kv: kv[1])[:8]
-    best_key, best_score = best_g.tobytes(), INF
-    best_ms = fit.exact_member_makespans(best_g)
-    for key, fval in ranked:
-        if not np.isfinite(fval):
-            continue
-        g = np.frombuffer(key, dtype=np.int64)
-        ms = fit.exact_member_makespans(g)
-        score = float(fit.scalarize(ms[None])[0])
-        if np.isfinite(score):
-            score += opts.port_weight * float(g.sum())
-        if score < best_score:
-            best_score, best_key, best_ms = score, key, ms
-    best_g = np.frombuffer(best_key, dtype=np.int64)
-    obj = float(fit.scalarize(best_ms[None])[0])
-    return RobustGAResult(
-        x=space.to_matrix(best_g), makespans=best_ms,
-        regrets=best_ms / refs, refs=refs,
-        weights=np.asarray(ensemble.weights), objective=objective,
-        objective_value=obj, generations=gen, evaluations=fit.evaluations,
-        elapsed=time.time() - t_start, history=history,
-        feasible=bool(np.isfinite(best_ms).all()))
+    return _ensemble_search(fit, seeds, objective, "delta_robust", t_start)
 
 
 # ----------------------------------------------------------- DELTA-Failsafe
@@ -682,42 +696,6 @@ def failure_scenarios(dag: CommDAG, num_planes: int = 4, k: int = 1,
     return out
 
 
-class FailsafeFitness(EnsembleFitness):
-    """k-failure fitness: ONE DAG scored under a stack of degradation
-    masks through the per-member mask lane of `EnsembleJaxDES`.  Reuses
-    the whole ensemble plumbing by treating each failure scenario as a
-    member whose DAG is the same object."""
-
-    def __init__(self, dag: CommDAG, scenarios: list[np.ndarray],
-                 space: TopologySpace, opts: GAOptions, objective: str,
-                 refs: np.ndarray):
-        super().__init__(DagEnsemble([dag] * len(scenarios)), space, opts,
-                         objective, refs)
-        self.masks = np.stack([np.asarray(m, dtype=np.float64)
-                               for m in scenarios])
-
-    def member_makespans(self, genomes: np.ndarray) -> np.ndarray:
-        genomes = np.asarray(genomes, dtype=np.int64).reshape(-1,
-                                                              self.space.E)
-        if self._jd is not None:
-            genomes, k = self._padded(genomes)
-            ms, feas = self._jd.ensemble_genome_makespan(
-                genomes, self.space.edge_u, self.space.edge_v,
-                masks=self.masks)
-            self.batch_calls += 1
-            return np.where(feas, ms, INF)[:k]
-        out = np.empty((len(genomes), len(self.problems)))
-        for s, x in enumerate(self.space.to_matrix_batch(genomes)):
-            out[s] = [simulate(p, x * m).makespan
-                      for p, m in zip(self.problems, self.masks)]
-        return out
-
-    def exact_member_makespans(self, genome: np.ndarray) -> np.ndarray:
-        x = self.space.to_matrix(genome)
-        return np.array([simulate(p, x * m).makespan
-                         for p, m in zip(self.problems, self.masks)])
-
-
 def delta_failsafe(dag: CommDAG, opts: GAOptions | None = None,
                    scenarios: list[np.ndarray] | None = None,
                    num_planes: int = 4, k: int = 1,
@@ -733,7 +711,8 @@ def delta_failsafe(dag: CommDAG, opts: GAOptions | None = None,
     `objective` is 'worst' (minimize the max scenario makespan) or
     'weighted' (uniform mean).  The repair policy also calls this with a
     single scenario -- the *current* fabric damage -- to produce a full
-    replan optimized for the degraded fabric.
+    replan optimized for the degraded fabric.  Each scenario is one
+    member of an `EnsembleFitness` over the same DAG, masked.
     """
     opts = opts or GAOptions()
     if objective not in FAILSAFE_OBJECTIVES:
@@ -745,52 +724,13 @@ def delta_failsafe(dag: CommDAG, opts: GAOptions | None = None,
     if not scenarios:
         raise ValueError("delta_failsafe needs at least one scenario")
     t_start = time.time()
-    rng = np.random.default_rng(opts.seed)
     space = TopologySpace(dag, xbar)
     refs = np.ones(len(scenarios))   # worst == max-regret w.r.t. unit refs
     eff = "max-regret" if objective == "worst" else "weighted"
-    fit = FailsafeFitness(dag, scenarios, space, opts, eff, refs)
-    t0 = time.time()
-
-    if space.E == 0:    # no inter-pod traffic: nothing to degrade
-        x = np.zeros((space.P, space.P), dtype=np.int64)
-        ms = fit.exact_member_makespans(np.zeros(0, dtype=np.int64))
-        obj = float(fit.scalarize(ms[None])[0])
-        return RobustGAResult(
-            x=x, makespans=ms, regrets=ms / refs, refs=refs,
-            weights=np.asarray(fit.ensemble.weights), objective=objective,
-            objective_value=obj, generations=0, evaluations=1,
-            elapsed=time.time() - t_start, history=[obj],
-            feasible=bool(np.isfinite(ms).all()))
-
-    with span("ga.evolve", kind="delta_failsafe", pop=opts.pop_size,
-              edges=space.E, members=len(scenarios)):
-        best_g, _, history, gen = _evolve(space, fit, opts, rng, t0, seeds)
-
-    # exact numpy re-rank per scenario (same f32-noise guard as the other
-    # engines: masked makespans are certified before the winner is named)
-    ranked = sorted(fit.cache.items(), key=lambda kv: kv[1])[:8]
-    best_key, best_score = best_g.tobytes(), INF
-    best_ms = fit.exact_member_makespans(best_g)
-    for key, fval in ranked:
-        if not np.isfinite(fval):
-            continue
-        g = np.frombuffer(key, dtype=np.int64)
-        ms = fit.exact_member_makespans(g)
-        score = float(fit.scalarize(ms[None])[0])
-        if np.isfinite(score):
-            score += opts.port_weight * float(g.sum())
-        if score < best_score:
-            best_score, best_key, best_ms = score, key, ms
-    best_g = np.frombuffer(best_key, dtype=np.int64)
-    obj = float(fit.scalarize(best_ms[None])[0])
-    return RobustGAResult(
-        x=space.to_matrix(best_g), makespans=best_ms,
-        regrets=best_ms / refs, refs=refs,
-        weights=np.asarray(fit.ensemble.weights), objective=objective,
-        objective_value=obj, generations=gen, evaluations=fit.evaluations,
-        elapsed=time.time() - t_start, history=history,
-        feasible=bool(np.isfinite(best_ms).all()))
+    fit = EnsembleFitness(DagEnsemble([dag] * len(scenarios)), space, opts,
+                          eff, refs, masks=scenarios)
+    return _ensemble_search(fit, seeds, objective, "delta_failsafe",
+                            t_start)
 
 
 # -------------------------------------------------------------- DELTA-Planes
@@ -874,13 +814,14 @@ class PlanesFitness(EnsembleFitness):
 
     The genome is the SPARE plane's lane only; the first k-1 lanes are
     frozen to the balanced split of the stage-A weighted optimum.  Every
-    candidate is scored across k+1 fabric states -- the full fabric plus
+    candidate is expanded into k+1 fabric states -- the full fabric plus
     each single plane dark (`plane_state_genomes`, the staggered-rewire /
-    PlaneFailure states the scheduler actually visits) -- and all M
-    ensemble members, in ONE fused `ensemble_genome_makespan` call over
-    the (S*(k+1), E) float state stack.  Objective: worst state/member
-    regret against the stage-A reference makespans, so the spare lane is
-    shaped to absorb whichever plane loss hurts the worst member most.
+    PlaneFailure states the scheduler actually visits) -- and the float
+    (S*(k+1), E) state stack is scored against all M ensemble members by
+    the ensemble fitness, in ONE fused `ensemble_genome_makespan` call.
+    Objective: worst state/member regret against the stage-A reference
+    makespans, so the spare lane is shaped to absorb whichever plane loss
+    hurts the worst member most.
     """
 
     def __init__(self, ensemble: DagEnsemble, base_lanes: np.ndarray,
@@ -890,62 +831,24 @@ class PlanesFitness(EnsembleFitness):
             .reshape(-1, space.E)
         self.num_planes = len(self.base_lanes) + 1
 
-    def _lane_stack(self, genomes: np.ndarray) -> np.ndarray:
-        """(S, E) spare lanes -> (S, k, E) full per-plane lane stacks."""
-        S = len(genomes)
-        base = np.broadcast_to(self.base_lanes[None],
-                               (S,) + self.base_lanes.shape)
-        return np.concatenate(
-            [base, genomes[:, None, :].astype(np.int64)], axis=1)
-
-    def state_makespans(self, genomes: np.ndarray) -> np.ndarray:
-        """(S, E) spare lanes -> (S, k+1, M) fabric-state makespans."""
+    def states(self, genomes: np.ndarray) -> np.ndarray:
+        """(S, E) spare lanes -> (S*(k+1), E) float fabric states."""
         from repro.core.des_jax import plane_state_genomes
-        genomes = np.asarray(genomes, dtype=np.int64).reshape(
-            -1, self.space.E)
-        S, M = len(genomes), len(self.problems)
-        k1 = self.num_planes + 1
-        states = plane_state_genomes(self._lane_stack(genomes)) \
-            .reshape(S * k1, self.space.E)
-        if self._jd is not None:
-            padded, n = self._padded(states)
-            ms, feas = self._jd.ensemble_genome_makespan(
-                padded, self.space.edge_u, self.space.edge_v)
-            self.batch_calls += 1
-            return np.where(feas, ms, INF)[:n].reshape(S, k1, M)
-        out = np.empty((S * k1, M))
-        for s, g in enumerate(states):
-            X = self._float_matrix(g)
-            out[s] = [simulate(p, X).makespan for p in self.problems]
-        return out.reshape(S, k1, M)
+        G = np.asarray(genomes, dtype=np.int64).reshape(-1, self.space.E)
+        base = np.broadcast_to(self.base_lanes[None],
+                               (len(G),) + self.base_lanes.shape)
+        lanes = np.concatenate([base, G[:, None, :]], axis=1)
+        return plane_state_genomes(lanes).reshape(-1, self.space.E)
 
-    def _float_matrix(self, g: np.ndarray) -> np.ndarray:
-        """Float scatter (fractional trickle lanes break `to_matrix`)."""
-        X = np.zeros((self.space.P, self.space.P))
-        X[self.space.edge_u, self.space.edge_v] = g
-        X[self.space.edge_v, self.space.edge_u] = g
-        return X
-
-    def exact_state_makespans(self, genome: np.ndarray) -> np.ndarray:
+    def exact(self, genome: np.ndarray) -> np.ndarray:
         """Exact (numpy DES) (k+1, M) state/member makespans of one
         spare lane."""
-        from repro.core.des_jax import plane_state_genomes
-        lanes = self._lane_stack(
-            np.asarray(genome, dtype=np.int64).reshape(1, -1))
-        states = plane_state_genomes(lanes)[0]          # (k+1, E)
-        out = np.empty((len(states), len(self.problems)))
-        for s, g in enumerate(states):
-            X = self._float_matrix(g)
-            out[s] = [simulate(p, X).makespan for p in self.problems]
-        return out
+        return np.array([self.exact_member_makespans(s)
+                         for s in self.states(genome)])
 
     def _raw_scores(self, genomes: np.ndarray) -> np.ndarray:
-        ms = self.state_makespans(genomes)              # (S, k+1, M)
-        flat = ms.reshape(len(ms), -1)
-        with np.errstate(invalid="ignore"):
-            out = (ms / self.refs).reshape(len(ms), -1).max(axis=1)
-        out[~np.isfinite(flat).all(axis=1)] = INF
-        return out
+        regrets = self.scalarize(self.member_makespans(self.states(genomes)))
+        return regrets.reshape(len(genomes), -1).max(axis=1)
 
 
 @dataclass
@@ -1035,50 +938,76 @@ def delta_planes(ensemble: DagEnsemble, opts: GAOptions | None = None,
     base_lanes = base_planes[:, space.edge_u, space.edge_v]
     fit = PlanesFitness(ensemble, base_lanes, space, opts, refs)
     rng = np.random.default_rng(opts.seed + 1)   # distinct from stage 1
-    t0 = time.time()
+    spare_g, exact, gen, history = _search(
+        fit, rng, seeds, "delta_planes", top=4,
+        members=ensemble.num_members, planes=num_planes)
+    planes = np.concatenate([base_planes, space.to_matrix(spare_g)[None]],
+                            axis=0)
+    lanes = np.concatenate([base_lanes, spare_g[None].astype(np.int64)],
+                           axis=0)
+    return PlanesGAResult(
+        planes=planes, lane_genomes=lanes, edges=list(space.edges),
+        x=planes.sum(axis=0), makespans=exact[0], dark_makespans=exact[1:],
+        refs=refs, plane_port_limits=tuple(map(tuple, budgets.tolist())),
+        objective_value=fit.exact_score(exact), generations=gen,
+        evaluations=fit.evaluations, elapsed=time.time() - t_start,
+        history=history, feasible=bool(np.isfinite(exact).all()))
 
-    def finish(spare_g: np.ndarray, gen: int,
-               history: list[float]) -> PlanesGAResult:
-        exact = fit.exact_state_makespans(spare_g)   # (k+1, M)
-        spare_x = space.to_matrix(spare_g)
-        planes = np.concatenate([base_planes, spare_x[None]], axis=0)
-        lanes = np.concatenate([base_lanes, spare_g[None].astype(np.int64)],
-                               axis=0)
-        with np.errstate(invalid="ignore"):
-            obj = float((exact / refs).max())
-        return PlanesGAResult(
-            planes=planes, lane_genomes=lanes, edges=list(space.edges),
-            x=planes.sum(axis=0), makespans=exact[0],
-            dark_makespans=exact[1:], refs=refs,
-            plane_port_limits=tuple(map(tuple, budgets.tolist())),
-            objective_value=obj, generations=gen,
-            evaluations=fit.evaluations, elapsed=time.time() - t_start,
-            history=history, feasible=bool(np.isfinite(exact).all()))
 
-    if space.E == 0:    # no inter-pod traffic: all-dark states are free
-        return finish(np.zeros(0, dtype=np.int64), 0, [])
+# ------------------------------------------------------------ port trimming
+def _trim_sweep(x: np.ndarray, eu: np.ndarray, ev: np.ndarray,
+                problems: list[DESProblem], budgets,
+                batch=None) -> np.ndarray:
+    """The greedy cyclic drop-one sweep both trimmers run.
 
-    with span("ga.evolve", kind="delta_planes", pop=opts.pop_size,
-              edges=space.E, members=ensemble.num_members,
-              planes=num_planes):
-        best_g, _, history, gen = _evolve(space, fit, opts, rng, t0, seeds)
+    Each round builds every drop-one candidate of `x` over the pairs
+    (eu, ev) and accepts the first, in cyclic pair order from the last
+    accepted drop, whose exact numpy makespan stays within `budgets` for
+    every problem; the sweep ends when no candidate is accepted.
 
-    # exact numpy re-rank of the top spare lanes across the full
-    # state/member matrix (f32-noise guard)
-    ranked = sorted(fit.cache.items(), key=lambda kv: kv[1])[:4]
-    best_key, best_score = best_g.tobytes(), INF
-    for key, fval in ranked:
-        if not np.isfinite(fval):
-            continue
-        g = np.frombuffer(key, dtype=np.int64)
-        exact = fit.exact_state_makespans(g)
-        with np.errstate(invalid="ignore"):
-            score = float((exact / refs).max())
-        if np.isfinite(score):
-            score += opts.port_weight * float(g.sum())
-        if score < best_score:
-            best_score, best_key = score, key
-    return finish(np.frombuffer(best_key, dtype=np.int64), gen, history)
+    `batch`, when given, maps the candidates (padded to E rows, so XLA
+    compiles once) to float32 (makespans, feasible) from the device DES
+    and turns on the `_trim_filter_bands` pre-filter: the first pass
+    certifies the filter-approved candidates, and the termination
+    backstop the ambiguous band (~fits & near) -- candidates the float32
+    filter rejected by less than `_TRIM_BACKSTOP_BAND` or flagged
+    infeasible, the only ones a bounded f32 DES error could have
+    misjudged -- so termination needs no exact re-check of every
+    clearly-over-budget candidate.  A float32 false negative mid-round can
+    at most reorder accepts relative to the serial sweep; every accept is
+    numpy-certified, so the budget is never violated.
+    """
+    E = len(eu)
+    checks = list(zip(problems, np.atleast_1d(budgets)))
+    ptr = 0   # cyclic sweep pointer
+    while True:
+        droppable = np.nonzero(x[eu, ev] > 1)[0]
+        k = len(droppable)
+        if k == 0:
+            return x
+        xs = np.repeat(x[None], k, axis=0)
+        rows = np.arange(k)
+        xs[rows, eu[droppable], ev[droppable]] -= 1
+        xs[rows, ev[droppable], eu[droppable]] -= 1
+        if batch is None:
+            passes = (np.ones(k, dtype=bool),)   # certified serially below
+        else:
+            ms, feas = batch(np.concatenate(
+                [xs, np.repeat(xs[:1], E - k, axis=0)]))
+            fits, near = _trim_filter_bands(ms, feas, budgets)
+            # a candidate is worth exact-checking only if EVERY member is
+            # in band: one member clearly over budget rejects it outright
+            fits = fits.reshape(E, -1).all(axis=1)[:k]
+            near = near.reshape(E, -1).all(axis=1)[:k]
+            passes = (fits, ~fits & near)
+        scan = np.argsort((droppable - ptr) % E, kind="stable")
+        hit = next((i for band in passes for i in scan if band[i]
+                    and all(simulate(p, xs[i]).makespan <= b
+                            for p, b in checks)), None)
+        if hit is None:
+            return x
+        x = xs[hit]
+        ptr = (int(droppable[hit]) + 1) % E
 
 
 def trim_ports_ensemble(ensemble: DagEnsemble, x: np.ndarray,
@@ -1089,42 +1018,32 @@ def trim_ports_ensemble(ensemble: DagEnsemble, x: np.ndarray,
     member's exact (numpy DES) makespan degrades beyond `rel_tol` of its
     value under the input topology.
 
-    Batched like the single-DAG `trim_ports`: each round scores all
-    drop-one candidates against all members in ONE
+    Batched like the single-DAG `trim_ports` (`_trim_sweep`): each round
+    scores all drop-one candidates against all members in ONE
     `EnsembleJaxDES.ensemble_genome_makespan` call (candidates x members
-    vmap over the shared compile bucket), then accepts the first fitting
-    drop in the legacy cyclic order after certifying it per member with
-    the exact numpy DES.  The float32 batch is a pre-filter only; the
-    termination backstop exact-rechecks the ambiguous band (see
-    `trim_ports`).  'auto' engages the batched path on wide fabrics
-    (large union-pair count with enough droppable circuits to amortize
-    the jit) and keeps the serial member sweep on fleet-scale ensembles
-    of small phase DAGs, where that is faster."""
+    vmap over the shared compile bucket).  'auto' engages the batched
+    path on wide fabrics (large union-pair count with enough droppable
+    circuits to amortize the jit) and keeps the serial member sweep on
+    fleet-scale ensembles of small phase DAGs, where that is faster."""
     problems = [DESProblem(m) for m in ensemble.members]
     x = np.asarray(x)
     base = np.array([simulate(p, x).makespan for p in problems])
     if not np.isfinite(base).all():
         return x
     x = x.copy()
-    budgets = base * (1 + rel_tol)
     pairs = ensemble.undirected_pairs()
     E = len(pairs)
     if E == 0:
         return x
     earr = np.asarray(pairs, dtype=np.int64).reshape(-1, 2)
     eu, ev = earr[:, 0], earr[:, 1]
-
-    def exact_fits(xt: np.ndarray) -> bool:
-        return all(simulate(p, xt).makespan <= b
-                   for p, b in zip(problems, budgets))
-
     droppable_total = int(np.maximum(x[eu, ev] - 1, 0).sum())
     # the genome view only covers the union pairs: circuits anywhere else
     # would be invisible to the batched scatter, so fall back to serial
     off_pair = x.copy()
     off_pair[eu, ev] = 0
     off_pair[ev, eu] = 0
-    jd = None
+    batch = None
     if off_pair.sum() == 0 and (
             backend == "jax"
             or (backend == "auto"
@@ -1133,49 +1052,10 @@ def trim_ports_ensemble(ensemble: DagEnsemble, x: np.ndarray,
         from repro.core.des_jax import EnsembleJaxDES
         jd = EnsembleJaxDES(problems)
 
-    ptr = 0   # cyclic sweep pointer (matches trim_ports' pair ordering)
-    while True:
-        droppable = np.nonzero(x[eu, ev] > 1)[0]
-        k = len(droppable)
-        if k == 0:
-            break
-        g0 = x[eu, ev].astype(np.int64)
-        G = np.repeat(g0[None], k, axis=0)
-        G[np.arange(k), droppable] -= 1
-        if jd is not None:
-            pad = E - k
-            batch = np.concatenate([G, np.repeat(G[:1], pad, axis=0)]) \
-                if pad > 0 else G
-            ms, feas = jd.ensemble_genome_makespan(batch, eu, ev)
-            fits, near = _trim_filter_bands(ms, feas, budgets)
-            # a candidate is worth exact-checking only if EVERY member is
-            # in band: one member clearly over budget rejects it outright
-            fits = fits.all(axis=1)[:k]
-            near = near.all(axis=1)[:k]
-        else:
-            fits = np.ones(k, dtype=bool)   # certified serially below
-            near = fits
-        accepted = False
-        scan = np.argsort((droppable - ptr) % E, kind="stable")
-        for certify_band in (fits, ~fits & near) if jd is not None \
-                else (fits,):
-            for i in scan:
-                if not certify_band[i]:
-                    continue
-                xt = x.copy()
-                e = droppable[i]
-                xt[eu[e], ev[e]] -= 1
-                xt[ev[e], eu[e]] -= 1
-                if exact_fits(xt):
-                    x = xt
-                    ptr = (int(e) + 1) % E
-                    accepted = True
-                    break
-            if accepted:
-                break
-        if not accepted:
-            break
-    return x
+        def batch(xs):
+            return jd.ensemble_genome_makespan(
+                xs[:, eu, ev].astype(np.int64), eu, ev)
+    return _trim_sweep(x, eu, ev, problems, base * (1 + rel_tol), batch)
 
 
 def trim_ports(dag: CommDAG, x: np.ndarray, rel_tol: float = 1e-6,
@@ -1185,28 +1065,19 @@ def trim_ports(dag: CommDAG, x: np.ndarray, rel_tol: float = 1e-6,
     removal leaves the DES makespan unchanged, exploiting the temporal
     slack of non-critical tasks.
 
-    Batched: each round scores *all* drop-one candidates from the current
-    topology in a single `JaxDES.batch_makespan` call (padded to a fixed
-    shape so XLA compiles once), then accepts the first fitting drop in the
-    legacy cyclic sweep order after certifying it against the exact numpy
-    DES.  The float32 batch is only a pre-filter (with a conservative
-    `_TRIM_FILTER_SLACK` margin): every accept is numpy-certified, so the
-    budget is never violated, and before terminating the sweep exact-
-    rechecks the batched scores' ambiguous band -- candidates the filter
-    rejected by less than `_TRIM_BACKSTOP_BAND`, or flagged infeasible by
-    the f32 engine: the only ones a bounded float32 DES error could have
-    misjudged -- so termination needs no serial numpy pass over every
-    clearly-over-budget candidate.  A float32 false
-    negative mid-round can at most reorder accepts relative to the serial
-    implementation; on the tested workloads the results are identical
-    (see tests/test_ga_vectorized.py).
+    Batched (`_trim_sweep`): each round scores *all* drop-one candidates
+    from the current topology in a single `JaxDES.batch_makespan` call,
+    then accepts the first fitting drop in the serial cyclic sweep order
+    after certifying it against the exact numpy DES.  The float32 batch
+    is only a pre-filter (with a conservative `_TRIM_FILTER_SLACK`
+    margin); on the tested workloads the results are identical to the
+    serial sweep (see tests/test_ga_vectorized.py).
     """
     problem = DESProblem(dag)
     base = simulate(problem, np.asarray(x)).makespan
     if not np.isfinite(base):
         return x
     x = np.asarray(x).copy()
-    budget = base * (1 + rel_tol)
     pairs = dag.undirected_pairs()
     E = len(pairs)
     if E == 0:
@@ -1217,60 +1088,15 @@ def trim_ports(dag: CommDAG, x: np.ndarray, rel_tol: float = 1e-6,
     # evaluates E candidates in a single max-lane while_loop pass, so it
     # needs a wide fabric (large E) plus enough potential drops to amortize
     # the one-time XLA compile; on narrow pipeline DAGs (E < 16) the serial
-    # numpy sweep is strictly faster and 'auto' keeps the legacy path
+    # numpy sweep is strictly faster
     droppable_total = int(np.maximum(x[eu, ev] - 1, 0).sum())
-    jd = None
+    batch = None
     if backend == "jax" or (backend == "auto"
                             and problem.n <= GAOptions.jax_task_limit
                             and E >= 16 and droppable_total >= 32):
         from repro.core.des_jax import JaxDES
-        jd = JaxDES(problem)
-
-    ptr = 0   # cyclic sweep pointer (matches the legacy pair ordering)
-    while True:
-        droppable = np.nonzero(x[eu, ev] > 1)[0]
-        k = len(droppable)
-        if k == 0:
-            break
-        xs = np.repeat(x[None], k, axis=0)
-        rows = np.arange(k)
-        xs[rows, eu[droppable], ev[droppable]] -= 1
-        xs[rows, ev[droppable], eu[droppable]] -= 1
-        if jd is not None:
-            pad = E - k
-            batch = np.concatenate([xs, np.repeat(xs[:1], pad, axis=0)]) \
-                if pad else xs
-            ms, feas = jd.batch_makespan(batch)
-            # float32 filter with slack; every accept is numpy-certified
-            fits, near = _trim_filter_bands(ms, feas, budget)
-            fits, near = fits[:k], near[:k]
-        else:
-            fits = np.ones(k, dtype=bool)   # certified serially below
-            near = fits
-        accepted = False
-        scan = np.argsort((droppable - ptr) % E, kind="stable")
-        # first pass: filter-approved candidates; termination backstop:
-        # the batched scores' ambiguous band (~fits & near) -- candidates
-        # the float32 filter rejected by less than _TRIM_BACKSTOP_BAND,
-        # the only ones a bounded f32 DES error could have misjudged.
-        # Rejections beyond the band need no exact re-check, so the
-        # termination round no longer re-simulates every candidate with
-        # the numpy DES.
-        for certify_band in ((fits, ~fits & near) if jd is not None
-                             else (fits,)):
-            for i in scan:
-                if not certify_band[i]:
-                    continue
-                if simulate(problem, xs[i]).makespan <= budget:
-                    x = xs[i]
-                    ptr = (int(droppable[i]) + 1) % E
-                    accepted = True
-                    break
-            if accepted:
-                break
-        if not accepted:
-            break
-    return x
+        batch = JaxDES(problem).batch_makespan
+    return _trim_sweep(x, eu, ev, [problem], base * (1 + rel_tol), batch)
 
 
 def exhaustive_search(dag: CommDAG, limit: int = 200000

@@ -1,10 +1,14 @@
-"""DELTA-Fast GA: exactness on small instances + Alg. 5/6 properties."""
+"""DELTA GA: exactness on small instances, Alg. 5/6 properties, and the
+pinned results of the four DELTA engines."""
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import repro.core.ga as ga
 from conftest import gpt7b_job, random_comm_dags
+from repro.core.cluster import ClusterSpec
+from repro.core.dag import CommDAG, DagEnsemble, make_virtual
 from repro.core.ga import (GAOptions, TopologySpace, delta_fast,
                            exhaustive_search)
 from repro.core.schedule import build_comm_dag
@@ -69,3 +73,90 @@ def test_infeasible_placement_raises():
     dag_bad = build_comm_dag(job)
     with pytest.raises(ValueError, match="infeasible"):
         TopologySpace(dag_bad)
+
+
+# ------------------------------------------------- pinned engine results
+# Generation-bounded runs of every DELTA engine on the small gpt-7b DAGs
+# (4 pods).  The literals pin the whole search: the topology, the exact
+# makespans, the generation and evaluation counts, and how many exact
+# numpy simulations (`repro.core.ga.simulate`) the engine runs -- the
+# re-rank scores its k best distinct genomes and then the winner once more.
+_PIN_OPTS = GAOptions(seed=7, pop_size=12, max_generations=6,
+                      patience=10**9, time_limit=1e9)
+_PINNED = {
+    "delta_fast": dict(
+        x=[[0, 2, 2, 0], [2, 0, 0, 1], [2, 0, 0, 0], [0, 1, 0, 0]],
+        makespans=[1.1119895697066666], generations=6, evaluations=12,
+        simulate_calls=9),
+    # two delta_fast reference runs (9 each), then 8 + 1 genomes x 2 members
+    "delta_robust": dict(
+        x=[[0, 2, 2, 0], [2, 0, 0, 1], [2, 0, 0, 0], [0, 1, 0, 0]],
+        makespans=[1.1119895697066666, 0.8040811983644446], generations=6,
+        evaluations=12, simulate_calls=36, objective_value=1.0),
+    # 8 + 1 genomes x 4 scenarios (healthy + one per active pair)
+    "delta_failsafe": dict(
+        x=[[0, 1, 3, 0], [1, 0, 0, 1], [3, 0, 0, 0], [0, 1, 0, 0]],
+        makespans=[0.8044167426844445, 0.8048641351111111,
+                   0.8044167426844445, 0.8044167426844445],
+        generations=6, evaluations=10, simulate_calls=36,
+        objective_value=0.8048641351111111),
+    # the base delta_robust (9 x 2), then the spare lane's re-rank
+    "delta_planes": dict(
+        x=[[0, 1, 3, 0], [1, 0, 0, 2], [3, 0, 0, 0], [0, 2, 0, 0]],
+        makespans=[1.1129962026666667, 0.8044167426844445],
+        dark_makespans=[[1.1183649117866665, 0.8071010972444445],
+                        [1.1129962026666667, 0.8044167426844445],
+                        [1.1129962026666667, 0.8044167426844445]],
+        generations=6, evaluations=5, simulate_calls=52,
+        objective_value=1.0048236544807043),
+}
+
+
+@pytest.mark.parametrize("engine", sorted(_PINNED))
+def test_engine_results_pinned(engine, small_dag, tiny_dag, monkeypatch):
+    calls = []
+    simulate = ga.simulate
+
+    def counted(*args, **kw):
+        calls.append(0)
+        return simulate(*args, **kw)
+
+    monkeypatch.setattr(ga, "simulate", counted)
+    ens = DagEnsemble([small_dag, tiny_dag])
+    res = {"delta_fast": lambda: ga.delta_fast(small_dag, _PIN_OPTS),
+           "delta_robust": lambda: ga.delta_robust(ens, _PIN_OPTS),
+           "delta_failsafe": lambda: ga.delta_failsafe(tiny_dag, _PIN_OPTS),
+           "delta_planes": lambda: ga.delta_planes(ens, _PIN_OPTS,
+                                                   num_planes=3)}[engine]()
+    want = _PINNED[engine]
+    assert res.x.tolist() == want["x"]
+    got_ms = [res.makespan] if engine == "delta_fast" else res.makespans
+    assert list(got_ms) == pytest.approx(want["makespans"], rel=1e-12)
+    if "dark_makespans" in want:
+        assert res.dark_makespans.ravel().tolist() == pytest.approx(
+            np.ravel(want["dark_makespans"]).tolist(), rel=1e-12)
+    if "objective_value" in want:
+        assert res.objective_value == pytest.approx(
+            want["objective_value"], rel=1e-12)
+    assert res.generations == want["generations"]
+    assert res.evaluations == want["evaluations"]
+    assert len(calls) == want["simulate_calls"]
+
+
+@pytest.mark.parametrize("engine", ["delta_fast", "delta_robust",
+                                    "delta_failsafe"])
+def test_engines_on_empty_pair_space(engine):
+    """No inter-pod traffic: every engine skips the search and returns the
+    empty topology after one exact evaluation."""
+    dag = CommDAG(tasks=[make_virtual()], deps=[],
+                  cluster=ClusterSpec(num_pods=2, port_limits=(2, 2),
+                                      nic_bandwidth=50e9))
+    res = {"delta_fast": lambda: ga.delta_fast(dag, _PIN_OPTS),
+           "delta_robust": lambda: ga.delta_robust(
+               DagEnsemble([dag]), _PIN_OPTS, refs=[1.0]),
+           "delta_failsafe": lambda: ga.delta_failsafe(dag, _PIN_OPTS)
+           }[engine]()
+    assert res.x.tolist() == [[0, 0], [0, 0]]
+    assert res.feasible
+    assert (res.generations, res.evaluations) == (0, 1)
+    assert len(res.history) == 1

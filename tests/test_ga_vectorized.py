@@ -1,13 +1,14 @@
 """Vectorized DELTA-Fast engine: batch-op equivalence with the scalar
 forms, feasibility invariants of the whole-population Alg. 5/6 ops, and
-no-regression guarantees against the legacy per-genome implementation."""
+no-regression guarantees against the serial per-genome engine it
+replaced (its makespans, and its port trimmer as the reference below)."""
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from conftest import gpt7b_job, random_comm_dags
-from repro.core import _ga_legacy as legacy
+from repro.core.dag import CommDAG
 from repro.core.des import DESProblem, simulate
 from repro.core.ga import GAOptions, TopologySpace, delta_fast, trim_ports
 from repro.core.schedule import build_comm_dag
@@ -16,6 +17,33 @@ from repro.core.schedule import build_comm_dag
 @pytest.fixture(scope="module")
 def dag():
     return build_comm_dag(gpt7b_job(4))
+
+
+def serial_trim_ports(dag: CommDAG, x: np.ndarray, rel_tol: float = 1e-6
+                      ) -> np.ndarray:
+    """Reference greedy port trimmer: sweep the pairs in order, dropping
+    one circuit at a time while the exact DES makespan stays within
+    budget, until a whole sweep drops nothing."""
+    problem = DESProblem(dag)
+    base = simulate(problem, x).makespan
+    if not np.isfinite(base):
+        return x
+    x = x.copy()
+    budget = base * (1 + rel_tol)
+    improved = True
+    while improved:
+        improved = False
+        for i, j in dag.undirected_pairs():
+            if x[i, j] <= 1:
+                continue
+            x[i, j] -= 1
+            x[j, i] -= 1
+            if simulate(problem, x).makespan <= budget:
+                improved = True
+            else:
+                x[i, j] += 1
+                x[j, i] += 1
+    return x
 
 
 # ----------------------------------------------------------- batch <-> scalar
@@ -87,24 +115,29 @@ def test_property_init_batch_feasible(dag, seed):
 
 
 # -------------------------------------------------- quality: no regression
+# Makespans of the serial per-genome engine the vectorized one replaced, at
+# the same seeds and options (both backends gave the same value; each run
+# ended on patience, not on its time limit).
+LEGACY_MAKESPAN_MB4 = 1.1119895697066666
+LEGACY_MAKESPAN_MB6 = 1.4198606583466669
+
+
 @pytest.mark.parametrize("backend", ["jax", "numpy"])
 def test_vectorized_no_worse_than_legacy(dag, backend):
     """Seeded runs of the vectorized engine must match or beat the
     pre-refactor engine's makespan on the small workloads."""
     kw = dict(seed=3, patience=20, time_limit=40, backend=backend)
     new = delta_fast(dag, GAOptions(**kw))
-    old = legacy.delta_fast(dag, legacy.GAOptions(**kw))
     assert new.feasible
-    assert new.makespan <= old.makespan * (1 + 1e-9)
+    assert new.makespan <= LEGACY_MAKESPAN_MB4 * (1 + 1e-9)
 
 
 def test_vectorized_no_worse_than_legacy_mb6():
     dag6 = build_comm_dag(gpt7b_job(6))
     kw = dict(seed=0, patience=15, time_limit=40)
     new = delta_fast(dag6, GAOptions(**kw))
-    old = legacy.delta_fast(dag6, legacy.GAOptions(**kw))
     assert new.feasible
-    assert new.makespan <= old.makespan * (1 + 1e-9)
+    assert new.makespan <= LEGACY_MAKESPAN_MB6 * (1 + 1e-9)
 
 
 # --------------------------------------------------------------- trim_ports
@@ -117,7 +150,7 @@ def test_trim_ports_identical_to_legacy(dag, backend):
     assert ok
     x_fat = space.to_matrix(g_fat)
     got = trim_ports(dag, x_fat, backend=backend)
-    want = legacy.trim_ports(dag, x_fat)
+    want = serial_trim_ports(dag, x_fat)
     assert (got == want).all()
     problem = DESProblem(dag)
     assert simulate(problem, got).makespan == \
